@@ -468,9 +468,7 @@ def main() -> int:
     args = ap.parse_args()
 
     from repro.core import machine
-    cache_dir = os.environ.get("NEXUS_XLA_CACHE")
-    machine.enable_persistent_compile_cache(
-        os.path.expanduser(cache_dir) if cache_dir else None)
+    machine.enable_persistent_compile_cache()
 
     os.makedirs(args.out, exist_ok=True)
     failures: list[str] = []

@@ -132,10 +132,7 @@ def bench_smoke(sizes=SIZES) -> dict:
         for b in builders.values():
             lanes.append(((w, h), b(cfg_for(w, h))))
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except (AttributeError, ValueError):
-        pass
+    jax.config.update("jax_enable_compilation_cache", False)
     machine.clear_engine_cache()
     t0 = time.time()
     for (w, h) in sizes:
@@ -183,10 +180,7 @@ def bench() -> dict:
 
     # Baseline emulation: no persistent compile cache, fresh in-process
     # engines, one batched run per mesh size (the PR-2 capability).
-    try:
-        jax.config.update("jax_compilation_cache_dir", None)
-    except (AttributeError, ValueError):
-        pass
+    jax.config.update("jax_enable_compilation_cache", False)
     machine.clear_engine_cache()
     t0 = time.time()
     per_size = {}

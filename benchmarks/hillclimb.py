@@ -25,10 +25,6 @@ import argparse
 import json
 import os
 
-from repro.launch import dryrun as dr
-from repro.launch import roofline as rl
-from repro.launch.mesh import make_production_mesh
-
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "perf")
 
 FABRIC_SIZES = [(2, 2), (2, 4), (4, 4), (4, 8), (8, 8)]
@@ -52,6 +48,10 @@ VARIANTS = {
 
 
 def run_variant(arch: str, shape: str, variant: str, *, pair: bool = True):
+    # imported here, not at module level: repro.launch.dryrun rewrites
+    # XLA_FLAGS (512 fake host devices) as it is imported, which the
+    # fabric autotuner must never see.
+    from repro.launch import dryrun as dr
     base = dr.TRAIN_POLICY.get(arch, ("dots", False, 1))
     ov = VARIANTS[variant]
     policy = (ov.get("remat", base[0]), ov.get("seqshard", base[1]),
@@ -66,6 +66,7 @@ def run_variant(arch: str, shape: str, variant: str, *, pair: bool = True):
 
 
 def terms(rec):
+    from repro.launch import roofline as rl
     flops = rec.get("flops_corrected", rec["flops_reported"])
     byts = rec.get("bytes_corrected", rec["bytes_reported"])
     coll = rec.get("coll_corrected", rec["collective_total"])

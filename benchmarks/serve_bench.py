@@ -220,9 +220,7 @@ def main() -> int:
                     help="also write the record as JSON here")
     args = ap.parse_args()
 
-    cache_dir = os.environ.get("NEXUS_XLA_CACHE")
-    machine.enable_persistent_compile_cache(
-        os.path.expanduser(cache_dir) if cache_dir else None)
+    machine.enable_persistent_compile_cache()
 
     fig17 = args.traffic == "fig17"
     copies = args.copies or 2

@@ -1162,29 +1162,59 @@ def clear_engine_cache() -> None:
     _ENGINE_CACHE.clear()
 
 
-def enable_persistent_compile_cache(path: str | None = None) -> str | None:
-    """Opt-in on-disk XLA compilation cache for sweep entry points.
+def enable_persistent_compile_cache() -> str:
+    """Turn on JAX's on-disk compilation cache for sweep entry points.
 
     The in-memory engine cache amortizes compiles within a process; this
     extends it across processes so re-running a sweep skips the one-time
-    engine compile entirely.  Best-effort: silently a no-op on jax builds
-    without the knobs.  Returns the cache dir actually set, or None.
+    engine compile entirely.  The directory is ``JAX_COMPILATION_CACHE_DIR``
+    when that is set (JAX reads it itself; no other directory is set),
+    else ``.jax_cache`` at the root of the checkout — a fixed path, since
+    the path is part of the cache key.  Returns the directory in use;
+    raises if JAX refuses the settings.
     """
     import os
-    if path is None:
-        path = os.path.join(os.path.expanduser("~"), ".cache",
-                            "nexus-machine-xla")
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))))
+        path = os.path.join(root, ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (AttributeError, ValueError):
-        return None
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 
 def engine_cache_size() -> int:
     return len(_ENGINE_CACHE)
+
+
+def lane_sharding(n_devices: int):
+    """``NamedSharding`` splitting a leading lane axis over the first
+    ``n_devices`` devices — the 1-D ``("lanes",)`` mesh the sharded
+    engine's ``shard_map`` runs on.  An explicit device subset, because
+    a caller may shard over fewer devices than the host exposes.  Lane
+    arrays placed with it already sit where the engine runs them, so no
+    chip-to-chip copy precedes a call and the donated state aliases."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.jax_compat import make_mesh
+    mesh = make_mesh((n_devices,), ("lanes",),
+                     devices=jax.devices()[:n_devices])
+    return NamedSharding(mesh, PartitionSpec("lanes"))
+
+
+def init_lanes(cfg: MachineConfig, static_ams, amq_len, mem_val, mem_meta,
+               sharding=None) -> MachineState:
+    """:func:`init_state` over a leading lane axis.  With a ``sharding``
+    (:func:`lane_sharding`) every leaf is created already split over the
+    lane mesh, so each device holds only its own lanes' state."""
+    init = jax.vmap(functools.partial(init_state, cfg))
+    if sharding is None:
+        return init(static_ams, amq_len, mem_val, mem_meta)
+    args = jax.device_put((static_ams, amq_len, mem_val, mem_meta), sharding)
+    return jax.jit(init, out_shardings=sharding)(*args)
 
 
 def _get_engine(cfg: MachineConfig, chunk: int, n_max: int | None = None,
@@ -1370,22 +1400,16 @@ def _get_engine(cfg: MachineConfig, chunk: int, n_max: int | None = None,
         return st, over, batch_idle(sub_ids, st), ticks
 
     if n_devices > 1:
-        from jax.sharding import PartitionSpec
-
-        from repro.jax_compat import make_mesh, shard_map_unchecked
-        # explicit device subset: the caller may shard over fewer
-        # devices than the host exposes (n_devices is capped at the
-        # batch size).
-        mesh = make_mesh((n_devices,), ("lanes",),
-                         devices=jax.devices()[:n_devices])
-        spec = PartitionSpec("lanes")
+        from repro.jax_compat import shard_map_unchecked
+        sharding = lane_sharding(n_devices)
+        spec = sharding.spec
         # A single spec per argument/result acts as a pytree prefix, so
         # every MachineState leaf splits on its leading lane axis too.
         # The (B, N) budget splits with its lanes: each device bounds
         # its own shard's PEs (its lanes may idle or exhaust their
         # budgets earlier, exactly like the unsharded engine).
         engine_fn = shard_map_unchecked(
-            engine_fn, mesh, in_specs=(spec,) * 7,
+            engine_fn, sharding.mesh, in_specs=(spec,) * 7,
             out_specs=(spec, spec, spec, spec))
     engine = jax.jit(engine_fn, donate_argnums=5)
 
@@ -1793,6 +1817,10 @@ def _run_many_impl(cfg: MachineConfig, workloads, *, modes=None, geoms=None,
             plan=(dev_plan if order is not None
                   else [list(range(workloads.batch))]))
 
+    # sharded: every lane array goes straight to the device that runs
+    # its lanes (device-major order matches the lane mesh's split)
+    sharding = lane_sharding(n_dev) if order is not None else None
+
     def lanes(a, pad_row=None):
         a = np.asarray(a, np.int32)
         if order is None:
@@ -1803,13 +1831,11 @@ def _run_many_impl(cfg: MachineConfig, workloads, *, modes=None, geoms=None,
                 out[pos] = a[lane]
             elif pad_row is not None:
                 out[pos] = pad_row
-        return jnp.asarray(out)
+        return jax.device_put(out, sharding)
 
-    st = jax.vmap(functools.partial(init_state, cfg))(
-        lanes(workloads.static_ams),
-        lanes(workloads.amq_len),
-        lanes(workloads.mem_val),
-        lanes(workloads.mem_meta))
+    st = init_lanes(cfg, lanes(workloads.static_ams),
+                    lanes(workloads.amq_len), lanes(workloads.mem_val),
+                    lanes(workloads.mem_meta), sharding=sharding)
     engine = _get_engine(cfg, chunk, n_max,
                          n_devices=n_dev if order is not None else 1)
     st, over, idle, ticks = engine(

@@ -1,68 +1,38 @@
-"""Version-compat shims for the jax API surface this repo spans.
+"""The few jax spellings this repo wraps, in one place.
 
-The code targets the current jax spelling of each API; this module maps it
-onto older releases (the container pins jax 0.4.x) so the same source runs
-on both.  Keep every version switch here — call sites import the symbol
-and stay version-agnostic.
+Written for the jax release ``pyproject.toml`` pins (0.9.x); there is no
+branch for any other release.  Call sites import the symbol from here so
+a future API move is a one-file change.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6: top-level function
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401  (re-exported for repro.sparse)
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across spellings.
+    """``jax.shard_map`` with replication checking off.
 
-    The flag is ``check_rep`` on jax 0.4.x and ``check_vma`` on newer
-    top-level ``jax.shard_map``; releases that accept neither get the
-    bare call (their checker handles the body or there is no flag).
     Used for per-shard-independent bodies (no collectives), where the
     checker only costs trace time.
     """
-    last_exc: TypeError | None = None
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-        except TypeError as e:
-            last_exc = e
-    # the bare final attempt passed no version-specific flag, so its
-    # TypeError is a genuine signature error — surface it, not a
-    # made-up "no spelling found".
-    raise last_exc
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new) / ``pltpu.TPUCompilerParams`` (old)."""
+    """``pltpu.CompilerParams`` for Pallas TPU kernels."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where supported
-    (``axis_types`` and ``jax.sharding.AxisType`` only exist on newer
-    jax; older releases treat every axis as Auto already).  Releases
-    below 0.4.35 predate ``jax.make_mesh`` entirely — there the mesh is
-    assembled directly from the device list."""
-    if not hasattr(jax, "make_mesh"):  # jax < 0.4.35
-        import numpy as np
-        devs = list(jax.devices()) if devices is None else list(devices)
-        n = int(np.prod(axis_shapes))
-        return jax.sharding.Mesh(
-            np.asarray(devs[:n]).reshape(axis_shapes), axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names, devices=devices,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:  # make_mesh predates the axis_types kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis of type Auto.
+
+    ``jax.make_mesh`` defaults its axes to Explicit, under which a ``jit``
+    over a multi-device mesh needs a ``jax.set_mesh`` context; the
+    ``shard_map`` bodies here spell their partitioning out themselves.
+    """
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

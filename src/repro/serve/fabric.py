@@ -61,7 +61,6 @@ a best-effort one):
 from __future__ import annotations
 
 import dataclasses
-import functools
 import threading
 import time
 from concurrent.futures import Future
@@ -74,7 +73,8 @@ from repro.core.am import C_NEXT_PC
 from repro.core.batch import RectPool, SubLane, _rebase_into_super, bucket
 from repro.core.machine import (MachineConfig, MachineState, RunResult,
                                 _get_engine, _host_stats, _pe_slice_result,
-                                init_state, mode_code, resolve_mode)
+                                init_lanes, lane_sharding, mode_code,
+                                resolve_mode)
 
 
 class ServiceError(RuntimeError):
@@ -469,6 +469,12 @@ class SweepService:
         return self.stats["occupancy_sum"] / n if n else 0.0
 
     @property
+    def n_devices(self) -> int:
+        """Devices the super-lane axis is split over (1 unsharded, or
+        before the arena is built)."""
+        return self._n_dev if self._built else 1
+
+    @property
     def telemetry(self):
         """Service-lifetime :class:`~repro.core.sweep.EngineTelemetry`
         (dead-step accounting across every slice so far)."""
@@ -565,6 +571,9 @@ class SweepService:
         self._n_dev = n_dev
         self._engine = _get_engine(cfg, self._chunk, n_max=n,
                                    n_devices=n_dev)
+        # sharded: the resident state lives split over the engine's lane
+        # mesh from the start, and every install keeps it there
+        sharding = lane_sharding(n_dev) if n_dev > 1 else None
 
         self._prog = np.zeros((b, self._n_slots * self._p_slot, cfg_f),
                               np.int32)
@@ -572,11 +581,11 @@ class SweepService:
         self._geoms = np.tile(np.array([[sw, sh]], np.int32), (b, 1))
         self._sub_ids = np.zeros((b, n), np.int32)
         self._local_ids = np.tile(np.arange(n, dtype=np.int32), (b, 1))
-        self._st = jax.vmap(functools.partial(init_state, cfg))(
-            np.zeros((b, n, self._q_cap, msg_f), np.int32),
+        self._st = init_lanes(
+            cfg, np.zeros((b, n, self._q_cap, msg_f), np.int32),
             np.zeros((b, n), np.int32),
             np.zeros((b, n, self._m_cap), np.int32),
-            np.zeros((b, n, self._m_cap, 2), np.int32))
+            np.zeros((b, n, self._m_cap, 2), np.int32), sharding=sharding)
         # host mirror of the per-PE cycle counters as of the last slice
         # boundary (installs zero their rows): the per-slice deadline
         # budgets and the dead-step telemetry read it without a sync
@@ -621,7 +630,8 @@ class SweepService:
         # produced corrupts them on CPU jax — the install allocates
         # fresh output buffers instead, only on admit slices, and the
         # engine keeps donating its state argument every slice.
-        self._install = jax.jit(_install_fn)
+        self._install = (jax.jit(_install_fn) if sharding is None else
+                         jax.jit(_install_fn, out_shardings=sharding))
 
         self._pools = [RectPool(self._super_geom) for _ in range(b)]
         self._free_slots = [set(range(self._n_slots)) for _ in range(b)]

@@ -1,10 +1,9 @@
 """Shared test configuration.
 
-CI wires the persistent XLA compile cache through here: when
-``NEXUS_XLA_CACHE`` is set (to a directory path, restored across runs by
-actions/cache), every engine compile in the suite is served from / saved
-to disk, so a warm-cache CI run skips the expensive one-time compiles
-entirely.  Local runs are unaffected unless the variable is exported.
+Tests run on the CPU backend (``JAX_PLATFORMS=cpu``).  The persistent
+XLA compile cache is JAX's own: exporting ``JAX_COMPILATION_CACHE_DIR``
+(as CI does, restored across runs by actions/cache) serves every engine
+compile in the suite from disk; nothing here sets another directory.
 
 Multi-device tests: the ``@pytest.mark.multidevice`` tier (the lane-
 sharding golden suite) needs more than one JAX device.  CPU-only hosts
@@ -16,16 +15,7 @@ get them by *forcing* host devices BEFORE jax initializes::
 device is visible and forcing is off, marked tests auto-skip; the
 ``n_devices`` fixture reports the session's device count either way.
 """
-import os
-
 import pytest
-
-
-def pytest_configure(config):
-    path = os.environ.get("NEXUS_XLA_CACHE")
-    if path:
-        from repro.core import machine
-        machine.enable_persistent_compile_cache(os.path.expanduser(path))
 
 
 def _device_count() -> int:

@@ -1,4 +1,5 @@
 """Sparse formats / ops / partitioning / dispatch — unit + property tests."""
+import os
 import subprocess
 import sys
 
@@ -182,13 +183,17 @@ for i in range(64):
     d = min(0.9, 0.02 + (i % 7) * 0.12)
     a[i] = (rng.random(64) < d) * rng.standard_normal(64)
 x = rng.standard_normal(64).astype(np.float32)
-mesh = jax.make_mesh((8,), ("data",), devices=jax.devices())
+from repro.jax_compat import make_mesh
+mesh = make_mesh((8,), ("data",), devices=jax.devices())
 sh = dispatch.shard_csr_rows(a, 8)
 y = dispatch.spmv_sharded(mesh, sh, x, capacity=int(sh["cap"]))
 assert np.allclose(y, a @ x, atol=1e-4), "mismatch"
 print("OK")
 """
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=600,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                         env={**os.environ, "PYTHONPATH": src,
+                              "JAX_PLATFORMS": "cpu"})
     assert "OK" in out.stdout, out.stderr[-2000:]
