@@ -1,0 +1,7 @@
+"""Share of the traced window in which a chip ran no operation, averaged
+over the chips the cell uses."""
+from chipbench.measures import device_idle_share
+
+
+def read(ctx):
+    return device_idle_share(ctx)
