@@ -114,6 +114,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import am
+from repro.core.spans import span
 from repro.core.am import (
     C_DSTSEL, C_NEXT_PC, C_OP, C_OP1SEL, C_OP2SEL, C_RESSEL, C_ROTATE, CFG_F,
     F_DST0, F_DST1, F_DST2, F_HOPS, F_OP, F_OP1, F_OP1C, F_OP2, F_OP2C, F_PC,
@@ -432,6 +433,12 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
     ``n_pes`` is the PE-axis *array length* (>= the largest lane's
     width*height under traced geometry; must equal ``cfg.n_pes`` on the
     static path).
+
+    Each section of the tick runs under a ``jax.named_scope``
+    (``cycle.credit``, ``cycle.route``, ``cycle.select``,
+    ``cycle.decode``, ``cycle.compute``, ``cycle.transfer``,
+    ``cycle.inject``, ``cycle.stats``): op metadata only, so a device
+    trace can put each operation down to its phase.
     """
     n = cfg.n_pes if n_pes is None else int(n_pes)
     if not cfg.traced_geometry:
@@ -537,477 +544,485 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
         head_v = st.buf_n > 0                          # (N,5)
 
         # --- downstream credit (ON/OFF flow control, T_OFF=1) -------------
-        # free slots at the input buffer each directional output feeds.
-        down_n = jnp.where(
-            nbr >= 0,
-            st.buf_n[jnp.clip(nbr, 0), opp[None, :].repeat(n, 0)],
-            DEPTH)                                     # (N,4)
-        credit_ok = (nbr >= 0) & (DEPTH - down_n >= 2)
+        with jax.named_scope("cycle.credit"):
+            # free slots at the input buffer each directional output feeds.
+            down_n = jnp.where(
+                nbr >= 0,
+                st.buf_n[jnp.clip(nbr, 0), opp[None, :].repeat(n, 0)],
+                DEPTH)                                     # (N,4)
+            credit_ok = (nbr >= 0) & (DEPTH - down_n >= 2)
 
         # --- route computation --------------------------------------------
-        via = heads[:, :, F_VIA]
-        dest_eff = jnp.where(via >= 0, via, heads[:, :, F_DST0])
-        out_port = route(dest_eff, credit_ok, w, xs, ys)   # (N,5)
-        at_dest = dest_eff == pe_ids[:, None]
-        # clear a reached Valiant waypoint: routing then targets DST0.
-        clear_via = head_v & (via >= 0) & at_dest
-        if act is not None:
-            clear_via = clear_via & act[:, None]
-        real_dest = heads[:, :, F_DST0] == pe_ids[:, None]
-        is_local = head_v & real_dest & (via < 0)
+        with jax.named_scope("cycle.route"):
+            via = heads[:, :, F_VIA]
+            dest_eff = jnp.where(via >= 0, via, heads[:, :, F_DST0])
+            out_port = route(dest_eff, credit_ok, w, xs, ys)   # (N,5)
+            at_dest = dest_eff == pe_ids[:, None]
+            # clear a reached Valiant waypoint: routing then targets DST0.
+            clear_via = head_v & (via >= 0) & at_dest
+            if act is not None:
+                clear_via = clear_via & act[:, None]
+            real_dest = heads[:, :, F_DST0] == pe_ids[:, None]
+            is_local = head_v & real_dest & (via < 0)
 
         # --- execution selection (dual-issue, Fig. 8b) ----------------------
-        # Each PE has TWO functional units the Input NI can feed per cycle:
-        # the *decode unit* (memory-class ops: loads, stores, stream accept)
-        # and the *compute unit* (ALU-class ops) — §3.3.1 lists them as
-        # separate blocks, and the Fig. 5 cycle trace relies on a MUL and
-        # the subsequent local memory update overlapping.  The Input NI may
-        # eject *any* buffered message destined here, not only the FIFO
-        # head — this removes head-of-line blocking behind a message whose
-        # stream unit is busy, which together with the deep pending FIFO
-        # gives the forward-progress guarantee the paper gets from bubble
-        # flow control + placement/timeouts (§3.4).
-        pend_free = PEND_CAP - st.pend_n               # (N,)
-        slot_v = jnp.arange(DEPTH)[None, None, :] < st.buf_n[:, :, None]
-        all_m = st.buf                                  # (N,5,D,F)
-        opn_a = all_m[..., F_OP]                        # (N,5,D)
-        local_a = slot_v & (all_m[..., F_DST0] == pe_ids[:, None, None]) & \
-            (all_m[..., F_VIA] < 0)
-        if active is not None:
-            # inactive (padded) PEs never execute; their buffers are empty
-            # anyway, so this mask is a defensive invariant, not a bit
-            # change on active PEs.
-            local_a = local_a & active[:, None, None]
-        if act is not None:
-            # budget-halted PEs execute nothing this tick
-            local_a = local_a & act[:, None, None]
-        # STREAM tasks are *always* consumable: they park in the stream-task
-        # wait queue (the TIA-style scheduler queue) until the decode unit is
-        # free, so they never clog the network (deadlock avoidance, §3.4).
-        swq_ok = st.swq_n < cfg.stream_wait_cap - 1
-        stream_a = opn_a == OP_STREAM
-        # Terminal stores emit nothing — always executable (drains the
-        # network regardless of pending back-pressure).
-        no_emit_a = (opn_a == OP_STORE_ADD) | (opn_a == OP_STORE_SET) | \
-            (stream_a & swq_ok[:, None, None])
-        mem_cand = local_a & is_mem_op(opn_a) & \
-            ((pend_free >= 1)[:, None, None] | no_emit_a) & \
-            (~stream_a | swq_ok[:, None, None])          # (N,5,D)
-        # Pending-FIFO reservation discipline (the consumption guarantee,
-        # §3.4).  Three producers may push in one cycle — decode output,
-        # compute output, stream spawn — and each is gated so occupancy
-        # provably never exceeds PEND_CAP:
-        #   * decode emits only with >= 1 free slot;
-        #   * compute emits only with >= 2 free slots (its own push PLUS a
-        #     same-cycle decode push: after both, pend_n <= PEND_CAP);
-        #   * the stream gate checks the *post-execution-push* count
-        #     against STREAM_THROTTLE (<= PEND_CAP - 3, asserted at module
-        #     scope), far below the cap.
-        # The run_many overflow guard trips at pend_n >= PEND_CAP - 2: the
-        # shallowest depth from which one more uncompensated cycle could
-        # gate an execution unit — i.e. consumption would no longer be
-        # unconditional (tests/test_pend_guard.py holds the invariant).
-        alu_cand = local_a & is_alu_op(opn_a) & \
-            (pend_free >= 2)[:, None, None]
-
-        def sel_dual():
-            # separate decode + compute units (Fig. 8b): one of each may
-            # retire per cycle.
-            return (_pick_one(mem_cand.reshape(n, PORTS * DEPTH),
-                              st.rr).reshape(n, PORTS, DEPTH),
-                    _pick_one(alu_cand.reshape(n, PORTS * DEPTH),
-                              st.rr + 2).reshape(n, PORTS, DEPTH))
-
-        def sel_single():
-            # TIA triggered dispatch: the priority encoder fires ONE ready
-            # instruction per PE per cycle (either unit).
-            sel_one = _pick_one((mem_cand | alu_cand)
-                                .reshape(n, PORTS * DEPTH),
-                                st.rr).reshape(n, PORTS, DEPTH)
-            return sel_one & is_mem_op(opn_a), sel_one & is_alu_op(opn_a)
-
-        sel_mem3, sel_alu3 = pick_mode(dual_on, sel_dual, sel_single)
-        any_alu_local = sel_alu3.any(axis=(1, 2))
-        opn = heads[:, :, F_OP]
-
-        def sel_opportunistic():
-            # in-network computing: an idle compute unit intercepts a
-            # passing ALU-class message whose operands are complete (head
-            # only).  Interception happens *in the router pipeline*: the
-            # message is transformed in place and continues from its input
-            # buffer next cycle — it never takes the pend/inject detour, so
-            # the cost is exactly one stalled-hop cycle (§3.1.3, Fig. 8a).
-            head_next_op = prog_j[jnp.clip(heads[:, :, F_PC], 0,
-                                           prog_j.shape[0] - 1), C_OP]
-            icand = (head_v & ~real_dest & (via < 0) & is_alu_op(opn)
-                     & (heads[:, :, F_OP1C] == 1) & (heads[:, :, F_OP2C] == 1)
-                     & (head_next_op != OP_NOP))
-            icand &= (~any_alu_local)[:, None]
+        with jax.named_scope("cycle.select"):
+            # Each PE has TWO functional units the Input NI can feed per cycle:
+            # the *decode unit* (memory-class ops: loads, stores, stream accept)
+            # and the *compute unit* (ALU-class ops) — §3.3.1 lists them as
+            # separate blocks, and the Fig. 5 cycle trace relies on a MUL and
+            # the subsequent local memory update overlapping.  The Input NI may
+            # eject *any* buffered message destined here, not only the FIFO
+            # head — this removes head-of-line blocking behind a message whose
+            # stream unit is busy, which together with the deep pending FIFO
+            # gives the forward-progress guarantee the paper gets from bubble
+            # flow control + placement/timeouts (§3.4).
+            pend_free = PEND_CAP - st.pend_n               # (N,)
+            slot_v = jnp.arange(DEPTH)[None, None, :] < st.buf_n[:, :, None]
+            all_m = st.buf                                  # (N,5,D,F)
+            opn_a = all_m[..., F_OP]                        # (N,5,D)
+            local_a = slot_v & (all_m[..., F_DST0] == pe_ids[:, None, None]) & \
+                (all_m[..., F_VIA] < 0)
             if active is not None:
-                icand &= active[:, None]
+                # inactive (padded) PEs never execute; their buffers are empty
+                # anyway, so this mask is a defensive invariant, not a bit
+                # change on active PEs.
+                local_a = local_a & active[:, None, None]
             if act is not None:
-                icand &= act[:, None]
-            return _pick_one(icand, st.rr + 1)
+                # budget-halted PEs execute nothing this tick
+                local_a = local_a & act[:, None, None]
+            # STREAM tasks are *always* consumable: they park in the stream-task
+            # wait queue (the TIA-style scheduler queue) until the decode unit is
+            # free, so they never clog the network (deadlock avoidance, §3.4).
+            swq_ok = st.swq_n < cfg.stream_wait_cap - 1
+            stream_a = opn_a == OP_STREAM
+            # Terminal stores emit nothing — always executable (drains the
+            # network regardless of pending back-pressure).
+            no_emit_a = (opn_a == OP_STORE_ADD) | (opn_a == OP_STORE_SET) | \
+                (stream_a & swq_ok[:, None, None])
+            mem_cand = local_a & is_mem_op(opn_a) & \
+                ((pend_free >= 1)[:, None, None] | no_emit_a) & \
+                (~stream_a | swq_ok[:, None, None])          # (N,5,D)
+            # Pending-FIFO reservation discipline (the consumption guarantee,
+            # §3.4).  Three producers may push in one cycle — decode output,
+            # compute output, stream spawn — and each is gated so occupancy
+            # provably never exceeds PEND_CAP:
+            #   * decode emits only with >= 1 free slot;
+            #   * compute emits only with >= 2 free slots (its own push PLUS a
+            #     same-cycle decode push: after both, pend_n <= PEND_CAP);
+            #   * the stream gate checks the *post-execution-push* count
+            #     against STREAM_THROTTLE (<= PEND_CAP - 3, asserted at module
+            #     scope), far below the cap.
+            # The run_many overflow guard trips at pend_n >= PEND_CAP - 2: the
+            # shallowest depth from which one more uncompensated cycle could
+            # gate an execution unit — i.e. consumption would no longer be
+            # unconditional (tests/test_pend_guard.py holds the invariant).
+            alu_cand = local_a & is_alu_op(opn_a) & \
+                (pend_free >= 2)[:, None, None]
 
-        sel_icept = pick_mode(opp_on, sel_opportunistic,
-                              lambda: jnp.zeros((n, PORTS), dtype=jnp.bool_))
-        icept3 = sel_icept[:, :, None] & (jnp.arange(DEPTH) == 0)[None, None, :]
-        sel_alu3 = sel_alu3 | icept3
-        # removal mask: locally-executed messages leave their FIFO;
-        # intercepted heads stay (transformed in place below).
-        sel_exec3 = (sel_mem3 | sel_alu3) & ~icept3
-        flat = all_m.reshape(n, PORTS * DEPTH, MSG_F)
-        msg = jnp.einsum("nkf,nk->nf", flat,
-                         sel_mem3.reshape(n, PORTS * DEPTH).astype(jnp.int32))
-        msg_alu = jnp.einsum(
-            "nkf,nk->nf", flat,
-            sel_alu3.reshape(n, PORTS * DEPTH).astype(jnp.int32))
-        was_icept = sel_icept.any(axis=1)               # (N,)
-        # heads busy this cycle (executed, or being transformed) do not
-        # request a network transit.
-        head_taken = (sel_mem3 | sel_alu3)[:, :, 0]
-        mv = sel_mem3.any(axis=(1, 2))                  # decode-unit fires
-        mv_alu = sel_alu3.any(axis=(1, 2))              # compute-unit fires
+            def sel_dual():
+                # separate decode + compute units (Fig. 8b): one of each may
+                # retire per cycle.
+                return (_pick_one(mem_cand.reshape(n, PORTS * DEPTH),
+                                  st.rr).reshape(n, PORTS, DEPTH),
+                        _pick_one(alu_cand.reshape(n, PORTS * DEPTH),
+                                  st.rr + 2).reshape(n, PORTS, DEPTH))
+
+            def sel_single():
+                # TIA triggered dispatch: the priority encoder fires ONE ready
+                # instruction per PE per cycle (either unit).
+                sel_one = _pick_one((mem_cand | alu_cand)
+                                    .reshape(n, PORTS * DEPTH),
+                                    st.rr).reshape(n, PORTS, DEPTH)
+                return sel_one & is_mem_op(opn_a), sel_one & is_alu_op(opn_a)
+
+            sel_mem3, sel_alu3 = pick_mode(dual_on, sel_dual, sel_single)
+            any_alu_local = sel_alu3.any(axis=(1, 2))
+            opn = heads[:, :, F_OP]
+
+            def sel_opportunistic():
+                # in-network computing: an idle compute unit intercepts a
+                # passing ALU-class message whose operands are complete (head
+                # only).  Interception happens *in the router pipeline*: the
+                # message is transformed in place and continues from its input
+                # buffer next cycle — it never takes the pend/inject detour, so
+                # the cost is exactly one stalled-hop cycle (§3.1.3, Fig. 8a).
+                head_next_op = prog_j[jnp.clip(heads[:, :, F_PC], 0,
+                                               prog_j.shape[0] - 1), C_OP]
+                icand = (head_v & ~real_dest & (via < 0) & is_alu_op(opn)
+                         & (heads[:, :, F_OP1C] == 1) & (heads[:, :, F_OP2C] == 1)
+                         & (head_next_op != OP_NOP))
+                icand &= (~any_alu_local)[:, None]
+                if active is not None:
+                    icand &= active[:, None]
+                if act is not None:
+                    icand &= act[:, None]
+                return _pick_one(icand, st.rr + 1)
+
+            sel_icept = pick_mode(opp_on, sel_opportunistic,
+                                  lambda: jnp.zeros((n, PORTS), dtype=jnp.bool_))
+            icept3 = sel_icept[:, :, None] & (jnp.arange(DEPTH) == 0)[None, None, :]
+            sel_alu3 = sel_alu3 | icept3
+            # removal mask: locally-executed messages leave their FIFO;
+            # intercepted heads stay (transformed in place below).
+            sel_exec3 = (sel_mem3 | sel_alu3) & ~icept3
+            flat = all_m.reshape(n, PORTS * DEPTH, MSG_F)
+            msg = jnp.einsum("nkf,nk->nf", flat,
+                             sel_mem3.reshape(n, PORTS * DEPTH).astype(jnp.int32))
+            msg_alu = jnp.einsum(
+                "nkf,nk->nf", flat,
+                sel_alu3.reshape(n, PORTS * DEPTH).astype(jnp.int32))
+            was_icept = sel_icept.any(axis=1)               # (N,)
+            # heads busy this cycle (executed, or being transformed) do not
+            # request a network transit.
+            head_taken = (sel_mem3 | sel_alu3)[:, :, 0]
+            mv = sel_mem3.any(axis=(1, 2))                  # decode-unit fires
+            mv_alu = sel_alu3.any(axis=(1, 2))              # compute-unit fires
 
         # ============== EXECUTE: DECODE UNIT (memory-class) ================
-        op = jnp.where(mv, msg[:, F_OP], OP_NOP)
-        pc = msg[:, F_PC]
-        cfg_row = prog_j[jnp.clip(pc, 0, prog_j.shape[0] - 1)]  # (N,CFG_F)
-        addr_res = jnp.clip(msg[:, F_RES], 0, cfg.mem_words - 1)
-        addr_op1 = jnp.clip(msg[:, F_OP1], 0, cfg.mem_words - 1)
-        addr_op2 = jnp.clip(msg[:, F_OP2], 0, cfg.mem_words - 1)
-        mem_r1 = jnp.take_along_axis(st.mem_val, addr_op1[:, None], 1)[:, 0]
-        mem_r2 = jnp.take_along_axis(st.mem_val, addr_op2[:, None], 1)[:, 0]
-        mem_rr = jnp.take_along_axis(st.mem_val, addr_res[:, None], 1)[:, 0]
-        meta_r = jnp.take_along_axis(
-            st.mem_meta, addr_res[:, None, None].repeat(2, 2), 1)[:, 0, :]
+        with jax.named_scope("cycle.decode"):
+            op = jnp.where(mv, msg[:, F_OP], OP_NOP)
+            pc = msg[:, F_PC]
+            cfg_row = prog_j[jnp.clip(pc, 0, prog_j.shape[0] - 1)]  # (N,CFG_F)
+            addr_res = jnp.clip(msg[:, F_RES], 0, cfg.mem_words - 1)
+            addr_op1 = jnp.clip(msg[:, F_OP1], 0, cfg.mem_words - 1)
+            addr_op2 = jnp.clip(msg[:, F_OP2], 0, cfg.mem_words - 1)
+            mem_r1 = jnp.take_along_axis(st.mem_val, addr_op1[:, None], 1)[:, 0]
+            mem_r2 = jnp.take_along_axis(st.mem_val, addr_op2[:, None], 1)[:, 0]
+            mem_rr = jnp.take_along_axis(st.mem_val, addr_res[:, None], 1)[:, 0]
+            meta_r = jnp.take_along_axis(
+                st.mem_meta, addr_res[:, None, None].repeat(2, 2), 1)[:, 0, :]
 
-        # -- memory writes (stores execute at the owner PE: ≤1 per PE) ------
-        do_add = mv & (op == OP_STORE_ADD)
-        do_set = mv & (op == OP_STORE_SET)
-        improved = msg[:, F_OP1] < mem_rr
-        do_min = mv & (op == OP_STORE_MIN) & improved
-        was_unset = mem_rr == UNSET
-        do_chk = mv & (op == OP_CHECKSET) & was_unset
-        new_word = jnp.where(do_add, mem_rr + msg[:, F_OP1],
-                    jnp.where(do_set | do_min | do_chk, msg[:, F_OP1], mem_rr))
-        write_mask = do_add | do_set | do_min | do_chk
-        mem_val = st.mem_val
-        mem_val = jax.vmap(
-            lambda row, a, v, m: row.at[a].set(jnp.where(m, v, row[a]))
-        )(mem_val, addr_res, new_word, write_mask)
+            # -- memory writes (stores execute at the owner PE: ≤1 per PE) ------
+            do_add = mv & (op == OP_STORE_ADD)
+            do_set = mv & (op == OP_STORE_SET)
+            improved = msg[:, F_OP1] < mem_rr
+            do_min = mv & (op == OP_STORE_MIN) & improved
+            was_unset = mem_rr == UNSET
+            do_chk = mv & (op == OP_CHECKSET) & was_unset
+            new_word = jnp.where(do_add, mem_rr + msg[:, F_OP1],
+                        jnp.where(do_set | do_min | do_chk, msg[:, F_OP1], mem_rr))
+            write_mask = do_add | do_set | do_min | do_chk
+            mem_val = st.mem_val
+            mem_val = jax.vmap(
+                lambda row, a, v, m: row.at[a].set(jnp.where(m, v, row[a]))
+            )(mem_val, addr_res, new_word, write_mask)
 
-        # -- outgoing dynamic AM construction --------------------------------
-        nxt = msg
-        nxt = nxt.at[:, F_OP].set(cfg_row[:, C_OP])
-        nxt = nxt.at[:, F_PC].set(cfg_row[:, C_NEXT_PC])
-        # LOADs fill an operand slot with the fetched word.
-        is_l1, is_l2 = op == OP_LOAD1, op == OP_LOAD2
-        nxt = nxt.at[:, F_OP1].set(jnp.where(is_l1, mem_r1, nxt[:, F_OP1]))
-        nxt = nxt.at[:, F_OP1C].set(jnp.where(is_l1, 1, nxt[:, F_OP1C]))
-        nxt = nxt.at[:, F_OP2].set(jnp.where(is_l2, mem_r2, nxt[:, F_OP2]))
-        nxt = nxt.at[:, F_OP2C].set(jnp.where(is_l2, 1, nxt[:, F_OP2C]))
-        rot = cfg_row[:, C_ROTATE] == 1
-        nxt = jnp.where(rot[:, None], _rotate_dsts(nxt), nxt)
-        nxt = nxt.at[:, F_VIA].set(-1)  # execution starts a fresh leg
-        nxt = maybe_anchor(nxt)
-        # Conditional continuations read the stored word's metadata:
-        #   BFS: next level = Op1+1, stream the discovered vertex's adjacency
-        #   SSSP: propagate the improved distance.
-        cont = do_min | do_chk
-        nxt = nxt.at[:, F_OP1].set(jnp.where(
-            do_chk, msg[:, F_OP1] + 1,
-            jnp.where(do_min, msg[:, F_OP1], nxt[:, F_OP1])))
-        nxt = nxt.at[:, F_OP2].set(jnp.where(cont, meta_r[:, 0], nxt[:, F_OP2]))
-        nxt = nxt.at[:, F_OP2C].set(jnp.where(cont, 0, nxt[:, F_OP2C]))
-        nxt = nxt.at[:, F_DST0].set(jnp.where(cont, meta_r[:, 1], nxt[:, F_DST0]))
-        nxt = nxt.at[:, F_DST1].set(jnp.where(cont, -1, nxt[:, F_DST1]))
-        nxt = nxt.at[:, F_DST2].set(jnp.where(cont, -1, nxt[:, F_DST2]))
+            # -- outgoing dynamic AM construction --------------------------------
+            nxt = msg
+            nxt = nxt.at[:, F_OP].set(cfg_row[:, C_OP])
+            nxt = nxt.at[:, F_PC].set(cfg_row[:, C_NEXT_PC])
+            # LOADs fill an operand slot with the fetched word.
+            is_l1, is_l2 = op == OP_LOAD1, op == OP_LOAD2
+            nxt = nxt.at[:, F_OP1].set(jnp.where(is_l1, mem_r1, nxt[:, F_OP1]))
+            nxt = nxt.at[:, F_OP1C].set(jnp.where(is_l1, 1, nxt[:, F_OP1C]))
+            nxt = nxt.at[:, F_OP2].set(jnp.where(is_l2, mem_r2, nxt[:, F_OP2]))
+            nxt = nxt.at[:, F_OP2C].set(jnp.where(is_l2, 1, nxt[:, F_OP2C]))
+            rot = cfg_row[:, C_ROTATE] == 1
+            nxt = jnp.where(rot[:, None], _rotate_dsts(nxt), nxt)
+            nxt = nxt.at[:, F_VIA].set(-1)  # execution starts a fresh leg
+            nxt = maybe_anchor(nxt)
+            # Conditional continuations read the stored word's metadata:
+            #   BFS: next level = Op1+1, stream the discovered vertex's adjacency
+            #   SSSP: propagate the improved distance.
+            cont = do_min | do_chk
+            nxt = nxt.at[:, F_OP1].set(jnp.where(
+                do_chk, msg[:, F_OP1] + 1,
+                jnp.where(do_min, msg[:, F_OP1], nxt[:, F_OP1])))
+            nxt = nxt.at[:, F_OP2].set(jnp.where(cont, meta_r[:, 0], nxt[:, F_OP2]))
+            nxt = nxt.at[:, F_OP2C].set(jnp.where(cont, 0, nxt[:, F_OP2C]))
+            nxt = nxt.at[:, F_DST0].set(jnp.where(cont, meta_r[:, 1], nxt[:, F_DST0]))
+            nxt = nxt.at[:, F_DST1].set(jnp.where(cont, -1, nxt[:, F_DST1]))
+            nxt = nxt.at[:, F_DST2].set(jnp.where(cont, -1, nxt[:, F_DST2]))
 
-        # Does the executed instruction emit a message?
-        terminal = (op == OP_STORE_ADD) | (op == OP_STORE_SET)
-        cond_no = ((op == OP_STORE_MIN) & ~improved) | \
-                  ((op == OP_CHECKSET) & ~was_unset)
-        starts_stream = mv & (op == OP_STREAM)
-        emits = mv & ~terminal & ~cond_no & ~starts_stream & \
-            (cfg_row[:, C_OP] != OP_NOP)
-        nxt = nxt.at[:, F_VALID].set(jnp.where(emits, 1, 0))
+            # Does the executed instruction emit a message?
+            terminal = (op == OP_STORE_ADD) | (op == OP_STORE_SET)
+            cond_no = ((op == OP_STORE_MIN) & ~improved) | \
+                      ((op == OP_CHECKSET) & ~was_unset)
+            starts_stream = mv & (op == OP_STREAM)
+            emits = mv & ~terminal & ~cond_no & ~starts_stream & \
+                (cfg_row[:, C_OP] != OP_NOP)
+            nxt = nxt.at[:, F_VALID].set(jnp.where(emits, 1, 0))
 
         # ============== EXECUTE: COMPUTE UNIT (ALU-class) ==================
-        op_a = jnp.where(mv_alu, msg_alu[:, F_OP], OP_NOP)
-        cfg_row_a = prog_j[jnp.clip(msg_alu[:, F_PC], 0,
-                                    prog_j.shape[0] - 1)]
-        alu_res = _alu(op_a, msg_alu[:, F_OP1], msg_alu[:, F_OP2],
-                       msg_alu[:, F_RES])
-        nxt_a = msg_alu
-        nxt_a = nxt_a.at[:, F_OP].set(cfg_row_a[:, C_OP])
-        nxt_a = nxt_a.at[:, F_PC].set(cfg_row_a[:, C_NEXT_PC])
-        nxt_a = nxt_a.at[:, F_OP1].set(
-            jnp.where(mv_alu, alu_res, nxt_a[:, F_OP1]))
-        nxt_a = nxt_a.at[:, F_OP1C].set(
-            jnp.where(mv_alu, 1, nxt_a[:, F_OP1C]))
-        # An anchored message (F_VIA == -2, TIA mode) has executed its local
-        # ALU op: resume the pushed-down destination list by rotating.
-        anchored_exec = mv_alu & (msg_alu[:, F_VIA] == -2)
-        rot_a = (cfg_row_a[:, C_ROTATE] == 1) | anchored_exec
-        nxt_a = jnp.where(rot_a[:, None], _rotate_dsts(nxt_a), nxt_a)
-        nxt_a = nxt_a.at[:, F_VIA].set(-1)
-        nxt_a = maybe_anchor(nxt_a)
-        emits_a = mv_alu & (cfg_row_a[:, C_OP] != OP_NOP)
-        nxt_a = nxt_a.at[:, F_VALID].set(jnp.where(emits_a, 1, 0))
+        with jax.named_scope("cycle.compute"):
+            op_a = jnp.where(mv_alu, msg_alu[:, F_OP], OP_NOP)
+            cfg_row_a = prog_j[jnp.clip(msg_alu[:, F_PC], 0,
+                                        prog_j.shape[0] - 1)]
+            alu_res = _alu(op_a, msg_alu[:, F_OP1], msg_alu[:, F_OP2],
+                           msg_alu[:, F_RES])
+            nxt_a = msg_alu
+            nxt_a = nxt_a.at[:, F_OP].set(cfg_row_a[:, C_OP])
+            nxt_a = nxt_a.at[:, F_PC].set(cfg_row_a[:, C_NEXT_PC])
+            nxt_a = nxt_a.at[:, F_OP1].set(
+                jnp.where(mv_alu, alu_res, nxt_a[:, F_OP1]))
+            nxt_a = nxt_a.at[:, F_OP1C].set(
+                jnp.where(mv_alu, 1, nxt_a[:, F_OP1C]))
+            # An anchored message (F_VIA == -2, TIA mode) has executed its local
+            # ALU op: resume the pushed-down destination list by rotating.
+            anchored_exec = mv_alu & (msg_alu[:, F_VIA] == -2)
+            rot_a = (cfg_row_a[:, C_ROTATE] == 1) | anchored_exec
+            nxt_a = jnp.where(rot_a[:, None], _rotate_dsts(nxt_a), nxt_a)
+            nxt_a = nxt_a.at[:, F_VIA].set(-1)
+            nxt_a = maybe_anchor(nxt_a)
+            emits_a = mv_alu & (cfg_row_a[:, C_OP] != OP_NOP)
+            nxt_a = nxt_a.at[:, F_VALID].set(jnp.where(emits_a, 1, 0))
 
-        # -- STREAM accept: push the stream task into the wait queue ---------
-        # The wait queue (like the pending FIFO below) is a circular buffer:
-        # push/pop are O(1) scatters/gathers instead of whole-array shifts,
-        # which keeps the per-cycle cost independent of queue capacity.
-        swq, swq_h, swq_n = st.swq, st.swq_h, st.swq_n
-        wpos = (swq_h + swq_n) % cfg.stream_wait_cap
-        swq = jax.vmap(
-            lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
-        )(swq, wpos, msg, starts_stream)
-        swq_n = swq_n + starts_stream.astype(jnp.int32)
+            # -- STREAM accept: push the stream task into the wait queue ---------
+            # The wait queue (like the pending FIFO below) is a circular buffer:
+            # push/pop are O(1) scatters/gathers instead of whole-array shifts,
+            # which keeps the per-cycle cost independent of queue capacity.
+            swq, swq_h, swq_n = st.swq, st.swq_h, st.swq_n
+            wpos = (swq_h + swq_n) % cfg.stream_wait_cap
+            swq = jax.vmap(
+                lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
+            )(swq, wpos, msg, starts_stream)
+            swq_n = swq_n + starts_stream.astype(jnp.int32)
 
-        # -- STREAM issue: an idle decode unit pops the next waiting task.
-        # Descriptor word (mem_val=base, meta0=count) at Op2 (address) — or
-        # at Res when Op2 holds a value (PageRank: Op2 carries the degree).
-        issue = (~st.stream_on) & (swq_n > 0)
-        if act is not None:
-            issue = issue & act
-        task = jnp.take_along_axis(
-            swq, swq_h[:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
-        t_res = jnp.clip(task[:, F_RES], 0, cfg.mem_words - 1)
-        t_op2 = jnp.clip(task[:, F_OP2], 0, cfg.mem_words - 1)
-        desc_a = jnp.where(task[:, F_OP2C] == 1, t_res, t_op2)
-        meta_d = jnp.take_along_axis(
-            st.mem_meta, desc_a[:, None, None].repeat(2, 2), 1)[:, 0, :]
-        s_base = jnp.take_along_axis(st.mem_val, desc_a[:, None], 1)[:, 0]
-        s_cnt = meta_d[:, 0]
-        stream_on = st.stream_on | (issue & (s_cnt > 0))
-        stream_msg = jnp.where(issue[:, None], task, st.stream_msg)
-        stream_base = jnp.where(issue, s_base, st.stream_base)
-        stream_left = jnp.where(issue, s_cnt, st.stream_left)
-        swq_h = (swq_h + issue.astype(jnp.int32)) % cfg.stream_wait_cap
-        swq_n = swq_n - issue.astype(jnp.int32)
+            # -- STREAM issue: an idle decode unit pops the next waiting task.
+            # Descriptor word (mem_val=base, meta0=count) at Op2 (address) — or
+            # at Res when Op2 holds a value (PageRank: Op2 carries the degree).
+            issue = (~st.stream_on) & (swq_n > 0)
+            if act is not None:
+                issue = issue & act
+            task = jnp.take_along_axis(
+                swq, swq_h[:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
+            t_res = jnp.clip(task[:, F_RES], 0, cfg.mem_words - 1)
+            t_op2 = jnp.clip(task[:, F_OP2], 0, cfg.mem_words - 1)
+            desc_a = jnp.where(task[:, F_OP2C] == 1, t_res, t_op2)
+            meta_d = jnp.take_along_axis(
+                st.mem_meta, desc_a[:, None, None].repeat(2, 2), 1)[:, 0, :]
+            s_base = jnp.take_along_axis(st.mem_val, desc_a[:, None], 1)[:, 0]
+            s_cnt = meta_d[:, 0]
+            stream_on = st.stream_on | (issue & (s_cnt > 0))
+            stream_msg = jnp.where(issue[:, None], task, st.stream_msg)
+            stream_base = jnp.where(issue, s_base, st.stream_base)
+            stream_left = jnp.where(issue, s_cnt, st.stream_left)
+            swq_h = (swq_h + issue.astype(jnp.int32)) % cfg.stream_wait_cap
+            swq_n = swq_n - issue.astype(jnp.int32)
 
-        # -- push executed-output AMs into the pending FIFO ------------------
-        # (decode-unit output, then compute-unit output: ≤2 pushes/cycle;
-        # circular buffer — see the stream wait queue above)
-        pend, pend_h, pend_n = st.pend, st.pend_h, st.pend_n
-        pos = (pend_h + pend_n) % PEND_CAP
-        pend = jax.vmap(
-            lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
-        )(pend, pos, nxt, emits)
-        pend_n = pend_n + emits.astype(jnp.int32)
-        emits_a_pend = emits_a & ~was_icept      # intercepted: in-place
-        pos_a = (pend_h + pend_n) % PEND_CAP
-        pend = jax.vmap(
-            lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
-        )(pend, pos_a, nxt_a, emits_a_pend)
-        pend_n = pend_n + emits_a_pend.astype(jnp.int32)
+            # -- push executed-output AMs into the pending FIFO ------------------
+            # (decode-unit output, then compute-unit output: ≤2 pushes/cycle;
+            # circular buffer — see the stream wait queue above)
+            pend, pend_h, pend_n = st.pend, st.pend_h, st.pend_n
+            pos = (pend_h + pend_n) % PEND_CAP
+            pend = jax.vmap(
+                lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
+            )(pend, pos, nxt, emits)
+            pend_n = pend_n + emits.astype(jnp.int32)
+            emits_a_pend = emits_a & ~was_icept      # intercepted: in-place
+            pos_a = (pend_h + pend_n) % PEND_CAP
+            pend = jax.vmap(
+                lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
+            )(pend, pos_a, nxt_a, emits_a_pend)
+            pend_n = pend_n + emits_a_pend.astype(jnp.int32)
 
-        # -- streaming decode: emit one spawned AM per cycle (backpressure-
-        # throttled, see STREAM_THROTTLE above) -------------------------------
-        can_emit = stream_on & (pend_n < STREAM_THROTTLE)
-        if act is not None:
-            can_emit = can_emit & act
-        e_addr = jnp.clip(stream_base, 0, cfg.mem_words - 1)
-        e_val = jnp.take_along_axis(mem_val, e_addr[:, None], 1)[:, 0]
-        e_meta = jnp.take_along_axis(
-            st.mem_meta, e_addr[:, None, None].repeat(2, 2), 1)[:, 0, :]
-        t = stream_msg
-        t_cfg = prog_j[jnp.clip(t[:, F_PC], 0, prog_j.shape[0] - 1)]
-        sp = t
-        sp = sp.at[:, F_VALID].set(1)
-        sp = sp.at[:, F_OP].set(t_cfg[:, C_OP])
-        sp = sp.at[:, F_PC].set(t_cfg[:, C_NEXT_PC])
-        o1 = jnp.select(
-            [t_cfg[:, C_OP1SEL] == 1, t_cfg[:, C_OP1SEL] == 2],
-            [e_val, t[:, F_OP1] + e_val], t[:, F_OP1])
-        o2 = jnp.select(
-            [t_cfg[:, C_OP2SEL] == 1, t_cfg[:, C_OP2SEL] == 2,
-             t_cfg[:, C_OP2SEL] == 3],
-            [e_val, e_meta[:, 0] + t[:, F_OP2], e_meta[:, 0] + t[:, F_OP1]],
-            t[:, F_OP2])
-        rs = jnp.select(
-            [t_cfg[:, C_RESSEL] == 1, t_cfg[:, C_RESSEL] == 2],
-            [t[:, F_RES] + e_meta[:, 0], e_meta[:, 0]], t[:, F_RES])
-        sp = sp.at[:, F_OP1].set(o1).at[:, F_OP1C].set(1)
-        sp = sp.at[:, F_OP2].set(o2)
-        sp = sp.at[:, F_OP2C].set(jnp.where(t_cfg[:, C_OP2SEL] > 0,
-                                            (t_cfg[:, C_OP2SEL] == 1)
-                                            .astype(jnp.int32),
-                                            t[:, F_OP2C]))
-        sp = sp.at[:, F_RES].set(rs)
-        use_meta_dst = t_cfg[:, C_DSTSEL] == 1
-        rot_t = _rotate_dsts(t)
-        sp = sp.at[:, F_DST0].set(
-            jnp.where(use_meta_dst, e_meta[:, 1], rot_t[:, F_DST0]))
-        sp = sp.at[:, F_DST1].set(
-            jnp.where(use_meta_dst, t[:, F_DST1], rot_t[:, F_DST1]))
-        sp = sp.at[:, F_DST2].set(
-            jnp.where(use_meta_dst, t[:, F_DST2], rot_t[:, F_DST2]))
-        sp = sp.at[:, F_VIA].set(-1)
-        sp = maybe_anchor(sp)
-        pos2 = (pend_h + pend_n) % PEND_CAP
-        pend = jax.vmap(
-            lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
-        )(pend, pos2, sp, can_emit)
-        pend_n = pend_n + can_emit.astype(jnp.int32)
-        stream_base = jnp.where(can_emit, stream_base + 1, stream_base)
-        stream_left = jnp.where(can_emit, stream_left - 1, stream_left)
-        stream_on = stream_on & (stream_left > 0)
+            # -- streaming decode: emit one spawned AM per cycle (backpressure-
+            # throttled, see STREAM_THROTTLE above) -------------------------------
+            can_emit = stream_on & (pend_n < STREAM_THROTTLE)
+            if act is not None:
+                can_emit = can_emit & act
+            e_addr = jnp.clip(stream_base, 0, cfg.mem_words - 1)
+            e_val = jnp.take_along_axis(mem_val, e_addr[:, None], 1)[:, 0]
+            e_meta = jnp.take_along_axis(
+                st.mem_meta, e_addr[:, None, None].repeat(2, 2), 1)[:, 0, :]
+            t = stream_msg
+            t_cfg = prog_j[jnp.clip(t[:, F_PC], 0, prog_j.shape[0] - 1)]
+            sp = t
+            sp = sp.at[:, F_VALID].set(1)
+            sp = sp.at[:, F_OP].set(t_cfg[:, C_OP])
+            sp = sp.at[:, F_PC].set(t_cfg[:, C_NEXT_PC])
+            o1 = jnp.select(
+                [t_cfg[:, C_OP1SEL] == 1, t_cfg[:, C_OP1SEL] == 2],
+                [e_val, t[:, F_OP1] + e_val], t[:, F_OP1])
+            o2 = jnp.select(
+                [t_cfg[:, C_OP2SEL] == 1, t_cfg[:, C_OP2SEL] == 2,
+                 t_cfg[:, C_OP2SEL] == 3],
+                [e_val, e_meta[:, 0] + t[:, F_OP2], e_meta[:, 0] + t[:, F_OP1]],
+                t[:, F_OP2])
+            rs = jnp.select(
+                [t_cfg[:, C_RESSEL] == 1, t_cfg[:, C_RESSEL] == 2],
+                [t[:, F_RES] + e_meta[:, 0], e_meta[:, 0]], t[:, F_RES])
+            sp = sp.at[:, F_OP1].set(o1).at[:, F_OP1C].set(1)
+            sp = sp.at[:, F_OP2].set(o2)
+            sp = sp.at[:, F_OP2C].set(jnp.where(t_cfg[:, C_OP2SEL] > 0,
+                                                (t_cfg[:, C_OP2SEL] == 1)
+                                                .astype(jnp.int32),
+                                                t[:, F_OP2C]))
+            sp = sp.at[:, F_RES].set(rs)
+            use_meta_dst = t_cfg[:, C_DSTSEL] == 1
+            rot_t = _rotate_dsts(t)
+            sp = sp.at[:, F_DST0].set(
+                jnp.where(use_meta_dst, e_meta[:, 1], rot_t[:, F_DST0]))
+            sp = sp.at[:, F_DST1].set(
+                jnp.where(use_meta_dst, t[:, F_DST1], rot_t[:, F_DST1]))
+            sp = sp.at[:, F_DST2].set(
+                jnp.where(use_meta_dst, t[:, F_DST2], rot_t[:, F_DST2]))
+            sp = sp.at[:, F_VIA].set(-1)
+            sp = maybe_anchor(sp)
+            pos2 = (pend_h + pend_n) % PEND_CAP
+            pend = jax.vmap(
+                lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
+            )(pend, pos2, sp, can_emit)
+            pend_n = pend_n + can_emit.astype(jnp.int32)
+            stream_base = jnp.where(can_emit, stream_base + 1, stream_base)
+            stream_left = jnp.where(can_emit, stream_left - 1, stream_left)
+            stream_on = stream_on & (stream_left > 0)
 
         # ==================== ALLOCATE & TRANSFER ==========================
-        req = head_v & ~head_taken & (out_port < 4)
-        # stalled LOCAL heads that could not execute this cycle:
-        stall_local = head_v & (out_port == OUT_LOCAL) & ~head_taken
-        if act is not None:
-            # budget-halted PEs neither request output ports nor accrue
-            # stall statistics — their whole tick is frozen.
-            req = req & act[:, None]
-            stall_local = stall_local & act[:, None]
-        grants = jnp.zeros((n, PORTS), dtype=jnp.bool_)
-        for o in range(4):  # separable output-side arbitration (unrolled)
-            cand_o = req & (out_port == o) & credit_ok[:, o][:, None]
-            g = _pick_one(cand_o, st.rr + o)
-            grants = grants | g
-        stall_net = req & ~grants
+        with jax.named_scope("cycle.transfer"):
+            req = head_v & ~head_taken & (out_port < 4)
+            # stalled LOCAL heads that could not execute this cycle:
+            stall_local = head_v & (out_port == OUT_LOCAL) & ~head_taken
+            if act is not None:
+                # budget-halted PEs neither request output ports nor accrue
+                # stall statistics — their whole tick is frozen.
+                req = req & act[:, None]
+                stall_local = stall_local & act[:, None]
+            grants = jnp.zeros((n, PORTS), dtype=jnp.bool_)
+            for o in range(4):  # separable output-side arbitration (unrolled)
+                cand_o = req & (out_port == o) & credit_ok[:, o][:, None]
+                g = _pick_one(cand_o, st.rr + o)
+                grants = grants | g
+            stall_net = req & ~grants
 
-        # removals: granted heads + the executed slot.  Stable compaction of
-        # each (pe, port) FIFO (≤2 removals per FIFO per cycle: one head in
-        # transit, one slot ejected).
-        removed = sel_exec3 | (grants[:, :, None]
-                               & (jnp.arange(DEPTH) == 0)[None, None, :])
-        keep = slot_v & ~removed                              # (N,5,D)
-        order = jnp.argsort(
-            jnp.where(keep, jnp.arange(DEPTH)[None, None, :], DEPTH + 1),
-            axis=2)                                           # kept first
-        buf = jnp.take_along_axis(
-            st.buf, order[..., None].repeat(MSG_F, 3), axis=2)
-        buf = jnp.where(
-            (jnp.arange(DEPTH)[None, None, :] < keep.sum(2)[..., None])
-            [..., None], buf, 0)
-        buf_n = keep.sum(axis=2).astype(jnp.int32)
-        # clear reached Valiant waypoints in-place on remaining heads.
-        popped0 = removed[:, :, 0]
-        buf = buf.at[:, :, 0, F_VIA].set(
-            jnp.where(clear_via & ~popped0, -1, buf[:, :, 0, F_VIA]))
-        # in-place interception write-back: the transformed message replaces
-        # the (un-removed, un-granted) head and routes onward next cycle.
-        icept_port = jnp.argmax(sel_icept, axis=1)      # (N,)
-        cur_head = buf[pe_ids, icept_port, 0, :]
-        buf = buf.at[pe_ids, icept_port, 0, :].set(
-            jnp.where(was_icept[:, None], nxt_a, cur_head))
+            # removals: granted heads + the executed slot.  Stable compaction of
+            # each (pe, port) FIFO (≤2 removals per FIFO per cycle: one head in
+            # transit, one slot ejected).
+            removed = sel_exec3 | (grants[:, :, None]
+                                   & (jnp.arange(DEPTH) == 0)[None, None, :])
+            keep = slot_v & ~removed                              # (N,5,D)
+            order = jnp.argsort(
+                jnp.where(keep, jnp.arange(DEPTH)[None, None, :], DEPTH + 1),
+                axis=2)                                           # kept first
+            buf = jnp.take_along_axis(
+                st.buf, order[..., None].repeat(MSG_F, 3), axis=2)
+            buf = jnp.where(
+                (jnp.arange(DEPTH)[None, None, :] < keep.sum(2)[..., None])
+                [..., None], buf, 0)
+            buf_n = keep.sum(axis=2).astype(jnp.int32)
+            # clear reached Valiant waypoints in-place on remaining heads.
+            popped0 = removed[:, :, 0]
+            buf = buf.at[:, :, 0, F_VIA].set(
+                jnp.where(clear_via & ~popped0, -1, buf[:, :, 0, F_VIA]))
+            # in-place interception write-back: the transformed message replaces
+            # the (un-removed, un-granted) head and routes onward next cycle.
+            icept_port = jnp.argmax(sel_icept, axis=1)      # (N,)
+            cur_head = buf[pe_ids, icept_port, 0, :]
+            buf = buf.at[pe_ids, icept_port, 0, :].set(
+                jnp.where(was_icept[:, None], nxt_a, cur_head))
 
-        # transfers: sender-side view — the message leaving each PE through
-        # each directional output port.
-        send_v = jnp.zeros((n, 4), dtype=jnp.bool_)
-        send_m = jnp.zeros((n, 4, MSG_F), dtype=jnp.int32)
-        for o in range(4):
-            sel_o = grants & (out_port == o)                  # (N,5)
-            send_v = send_v.at[:, o].set(sel_o.any(axis=1))
-            send_m = send_m.at[:, o, :].set(
-                jnp.einsum("npf,np->nf", heads, sel_o.astype(jnp.int32)))
-        # receiver-side gather: input port q of PE r is fed by neighbor
-        # nbr[r, q] transmitting through its output opp[q].  Pure gather —
-        # no duplicate-scatter hazards; ≤1 arrival per (pe, port).
-        for q in range(4):
-            s = nbr[:, q]                                     # sender id
-            o = int(opp_np[q])                                # sender output
-            has = (s >= 0) & send_v[jnp.clip(s, 0), o]
-            m_in = send_m[jnp.clip(s, 0), o, :]
-            m_in = m_in.at[:, F_HOPS].add(1)
-            pos_d = jnp.clip(buf_n[:, q], 0, DEPTH - 1)
-            cur = buf[pe_ids, q, pos_d, :]
-            buf = buf.at[pe_ids, q, pos_d, :].set(
-                jnp.where(has[:, None], m_in, cur))
-            buf_n = buf_n.at[:, q].add(has.astype(jnp.int32))
+            # transfers: sender-side view — the message leaving each PE through
+            # each directional output port.
+            send_v = jnp.zeros((n, 4), dtype=jnp.bool_)
+            send_m = jnp.zeros((n, 4, MSG_F), dtype=jnp.int32)
+            for o in range(4):
+                sel_o = grants & (out_port == o)                  # (N,5)
+                send_v = send_v.at[:, o].set(sel_o.any(axis=1))
+                send_m = send_m.at[:, o, :].set(
+                    jnp.einsum("npf,np->nf", heads, sel_o.astype(jnp.int32)))
+            # receiver-side gather: input port q of PE r is fed by neighbor
+            # nbr[r, q] transmitting through its output opp[q].  Pure gather —
+            # no duplicate-scatter hazards; ≤1 arrival per (pe, port).
+            for q in range(4):
+                s = nbr[:, q]                                     # sender id
+                o = int(opp_np[q])                                # sender output
+                has = (s >= 0) & send_v[jnp.clip(s, 0), o]
+                m_in = send_m[jnp.clip(s, 0), o, :]
+                m_in = m_in.at[:, F_HOPS].add(1)
+                pos_d = jnp.clip(buf_n[:, q], 0, DEPTH - 1)
+                cur = buf[pe_ids, q, pos_d, :]
+                buf = buf.at[pe_ids, q, pos_d, :].set(
+                    jnp.where(has[:, None], m_in, cur))
+                buf_n = buf_n.at[:, q].add(has.astype(jnp.int32))
 
         # ==================== INJECTION (AM NIC, §3.3.1) ====================
-        inj_space = buf_n[:, P_INJ] < DEPTH
-        if active is not None:
-            inj_space = inj_space & active
-        if act is not None:
-            inj_space = inj_space & act
-        have_dyn = pend_n > 0
-        have_stat = st.amq_head < st.amq_len
-        inj_dyn = inj_space & have_dyn
-        inj_stat = inj_space & ~have_dyn & have_stat
-        dyn_msg = jnp.take_along_axis(
-            pend, pend_h[:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
-        stat_msg = jnp.take_along_axis(
-            st.amq, jnp.clip(st.amq_head, 0, st.amq.shape[1] - 1)
-            [:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
-        inj_msg = jnp.where(inj_dyn[:, None], dyn_msg, stat_msg)
+        with jax.named_scope("cycle.inject"):
+            inj_space = buf_n[:, P_INJ] < DEPTH
+            if active is not None:
+                inj_space = inj_space & active
+            if act is not None:
+                inj_space = inj_space & act
+            have_dyn = pend_n > 0
+            have_stat = st.amq_head < st.amq_len
+            inj_dyn = inj_space & have_dyn
+            inj_stat = inj_space & ~have_dyn & have_stat
+            dyn_msg = jnp.take_along_axis(
+                pend, pend_h[:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
+            stat_msg = jnp.take_along_axis(
+                st.amq, jnp.clip(st.amq_head, 0, st.amq.shape[1] - 1)
+                [:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
+            inj_msg = jnp.where(inj_dyn[:, None], dyn_msg, stat_msg)
 
-        def inj_valiant():
-            # TIA-Valiant: ROMM-style randomized *minimal-path* routing
-            # (paper cites [33, 48]) — the waypoint is drawn inside the
-            # src→dst bounding box, so each leg keeps the same per-axis
-            # direction signs and the west-first turn model stays
-            # deadlock-free.  Anchored (-2)/self messages are exempt.
-            h = (sub_local.astype(jnp.uint32) * jnp.uint32(2654435761)
-                 + st.cycle.astype(jnp.uint32) * jnp.uint32(40503))
-            dstp = jnp.clip(inj_msg[:, F_DST0], 0)
-            dx = dstp % w - xs
-            dy = dstp // w - ys
-            rx = (h % (jnp.abs(dx).astype(jnp.uint32) + 1)).astype(jnp.int32)
-            ry = ((h >> 8) % (jnp.abs(dy).astype(jnp.uint32) + 1)) \
-                .astype(jnp.int32)
-            # West-first legality across the two legs: a waypoint with
-            # via_x > dst_x would force a W hop *after* leg 1's N/S hops —
-            # an illegal turn into W (deadlock, observed as a credit cycle).
-            # For westbound traffic pin via_x = dst_x (all W hops happen
-            # first, inside leg 1) and randomize only y; eastbound keeps
-            # full in-box randomization (no W hops at all).
-            rx = jnp.where(dx < 0, jnp.abs(dx), rx)
-            via_pe = (ys + jnp.sign(dy) * ry) * w + (xs + jnp.sign(dx) * rx)
-            eligible = (inj_msg[:, F_VIA] == -1) & \
-                (inj_msg[:, F_DST0] != pe_ids) & (via_pe != pe_ids) & \
-                (via_pe != inj_msg[:, F_DST0])
-            return inj_msg.at[:, F_VIA].set(
-                jnp.where(eligible, via_pe, inj_msg[:, F_VIA]))
+            def inj_valiant():
+                # TIA-Valiant: ROMM-style randomized *minimal-path* routing
+                # (paper cites [33, 48]) — the waypoint is drawn inside the
+                # src→dst bounding box, so each leg keeps the same per-axis
+                # direction signs and the west-first turn model stays
+                # deadlock-free.  Anchored (-2)/self messages are exempt.
+                h = (sub_local.astype(jnp.uint32) * jnp.uint32(2654435761)
+                     + st.cycle.astype(jnp.uint32) * jnp.uint32(40503))
+                dstp = jnp.clip(inj_msg[:, F_DST0], 0)
+                dx = dstp % w - xs
+                dy = dstp // w - ys
+                rx = (h % (jnp.abs(dx).astype(jnp.uint32) + 1)).astype(jnp.int32)
+                ry = ((h >> 8) % (jnp.abs(dy).astype(jnp.uint32) + 1)) \
+                    .astype(jnp.int32)
+                # West-first legality across the two legs: a waypoint with
+                # via_x > dst_x would force a W hop *after* leg 1's N/S hops —
+                # an illegal turn into W (deadlock, observed as a credit cycle).
+                # For westbound traffic pin via_x = dst_x (all W hops happen
+                # first, inside leg 1) and randomize only y; eastbound keeps
+                # full in-box randomization (no W hops at all).
+                rx = jnp.where(dx < 0, jnp.abs(dx), rx)
+                via_pe = (ys + jnp.sign(dy) * ry) * w + (xs + jnp.sign(dx) * rx)
+                eligible = (inj_msg[:, F_VIA] == -1) & \
+                    (inj_msg[:, F_DST0] != pe_ids) & (via_pe != pe_ids) & \
+                    (via_pe != inj_msg[:, F_DST0])
+                return inj_msg.at[:, F_VIA].set(
+                    jnp.where(eligible, via_pe, inj_msg[:, F_VIA]))
 
-        inj_msg = pick_mode(val_on, inj_valiant, lambda: inj_msg)
-        do_inj = inj_dyn | inj_stat
-        net_inj = do_inj
-        posi = jnp.clip(buf_n[:, P_INJ], 0, DEPTH - 1)
-        buf = jax.vmap(
-            lambda b, i, v, m: jnp.where(m, b.at[P_INJ, i].set(v), b)
-        )(buf, posi, inj_msg, net_inj)
-        buf_n = buf_n.at[:, P_INJ].add(net_inj.astype(jnp.int32))
-        # consume sources
-        pend_h = (pend_h + inj_dyn.astype(jnp.int32)) % PEND_CAP
-        pend_n = pend_n - inj_dyn.astype(jnp.int32)
-        amq_head = st.amq_head + inj_stat.astype(jnp.int32)
+            inj_msg = pick_mode(val_on, inj_valiant, lambda: inj_msg)
+            do_inj = inj_dyn | inj_stat
+            net_inj = do_inj
+            posi = jnp.clip(buf_n[:, P_INJ], 0, DEPTH - 1)
+            buf = jax.vmap(
+                lambda b, i, v, m: jnp.where(m, b.at[P_INJ, i].set(v), b)
+            )(buf, posi, inj_msg, net_inj)
+            buf_n = buf_n.at[:, P_INJ].add(net_inj.astype(jnp.int32))
+            # consume sources
+            pend_h = (pend_h + inj_dyn.astype(jnp.int32)) % PEND_CAP
+            pend_n = pend_n - inj_dyn.astype(jnp.int32)
+            amq_head = st.amq_head + inj_stat.astype(jnp.int32)
 
         # ==================== STATS =========================================
-        # All per-PE: totals are reductions at result-extraction time, and
-        # under sub-mesh packing each co-tenant's slice freezes at its own
-        # idle point (hops are attributed to the sending PE — a hop's two
-        # endpoints always belong to the same sub-mesh).
-        busy = mv | mv_alu | can_emit
-        st_busy = st.st_busy + busy.astype(jnp.int32)
-        st_exec = st.st_exec + mv.astype(jnp.int32) + mv_alu.astype(jnp.int32)
-        st_enroute = st.st_enroute + sel_icept.any(axis=1).astype(jnp.int32)
-        st_stall = st.st_stall + (stall_net | stall_local).astype(jnp.int32)
-        st_hops = st.st_hops + grants.sum(axis=1).astype(jnp.int32)
-        st_inj = st.st_inj + do_inj.astype(jnp.int32)
+        with jax.named_scope("cycle.stats"):
+            # All per-PE: totals are reductions at result-extraction time, and
+            # under sub-mesh packing each co-tenant's slice freezes at its own
+            # idle point (hops are attributed to the sending PE — a hop's two
+            # endpoints always belong to the same sub-mesh).
+            busy = mv | mv_alu | can_emit
+            st_busy = st.st_busy + busy.astype(jnp.int32)
+            st_exec = st.st_exec + mv.astype(jnp.int32) + mv_alu.astype(jnp.int32)
+            st_enroute = st.st_enroute + sel_icept.any(axis=1).astype(jnp.int32)
+            st_stall = st.st_stall + (stall_net | stall_local).astype(jnp.int32)
+            st_hops = st.st_hops + grants.sum(axis=1).astype(jnp.int32)
+            st_inj = st.st_inj + do_inj.astype(jnp.int32)
 
-        # budget-halted PEs also freeze their cycle counter and round-robin
-        # pointer, preserving the rr ≡ cycle (mod PORTS) alignment that
-        # drives arbitration when a sliced run later resumes.
-        tick = jnp.int32(1) if act is None else act.astype(jnp.int32)
-        return MachineState(
-            buf=buf, buf_n=buf_n, amq=st.amq, amq_head=amq_head,
-            amq_len=st.amq_len, pend=pend, pend_h=pend_h, pend_n=pend_n,
-            mem_val=mem_val,
-            mem_meta=st.mem_meta, stream_on=stream_on, stream_msg=stream_msg,
-            stream_base=stream_base, stream_left=stream_left, swq=swq,
-            swq_h=swq_h, swq_n=swq_n, rr=(st.rr + tick) % PORTS,
-            cycle=st.cycle + tick,
-            st_busy=st_busy, st_exec=st_exec, st_enroute=st_enroute,
-            st_stall=st_stall, st_hops=st_hops, st_inj=st_inj)
+            # budget-halted PEs also freeze their cycle counter and round-robin
+            # pointer, preserving the rr ≡ cycle (mod PORTS) alignment that
+            # drives arbitration when a sliced run later resumes.
+            tick = jnp.int32(1) if act is None else act.astype(jnp.int32)
+            return MachineState(
+                buf=buf, buf_n=buf_n, amq=st.amq, amq_head=amq_head,
+                amq_len=st.amq_len, pend=pend, pend_h=pend_h, pend_n=pend_n,
+                mem_val=mem_val,
+                mem_meta=st.mem_meta, stream_on=stream_on, stream_msg=stream_msg,
+                stream_base=stream_base, stream_left=stream_left, swq=swq,
+                swq_h=swq_h, swq_n=swq_n, rr=(st.rr + tick) % PORTS,
+                cycle=st.cycle + tick,
+                st_busy=st_busy, st_exec=st_exec, st_enroute=st_enroute,
+                st_stall=st_stall, st_hops=st_hops, st_inj=st_inj)
 
     return cycle
 
@@ -1279,6 +1294,12 @@ def _get_engine(cfg: MachineConfig, chunk: int, n_max: int | None = None,
     shard of lanes is idle — no cross-device sync per chunk, and
     per-lane state transitions are the exact integer program of the
     unsharded engine: sharded metrics are bit-identical.
+
+    Beside the cycle's own scopes (:func:`_make_cycle`), the engine's
+    parts run under ``engine.freeze`` (the idle/halt masking of
+    ``lane_step``), ``engine.ff_probe`` (the lone-flight probe),
+    ``engine.ff_step`` (the fast-forward rewrite) and ``engine.guard``
+    (the pending-FIFO check).
     """
     n_max = cfg.n_pes if n_max is None else int(n_max)
     key = _engine_key(cfg, n_max, chunk, n_devices)
@@ -1305,33 +1326,36 @@ def _get_engine(cfg: MachineConfig, chunk: int, n_max: int | None = None,
             # copying the multi-MB queue arrays each cycle; masking the
             # cheap observable leaves keeps per-cycle cost independent
             # of queue capacities.
-            spent = st.cycle - c0
-            halt = spent >= budget
-            alive = (~group_idle(st, sub_id)) & (st.cycle < cfg.max_cycles) \
-                & ~halt
+            with jax.named_scope("engine.freeze"):
+                spent = st.cycle - c0
+                halt = spent >= budget
+                alive = (~group_idle(st, sub_id)) \
+                    & (st.cycle < cfg.max_cycles) & ~halt
             st2 = cyc(prog, mode, geom, st, local_id, halt=halt)
 
             def keep(new, old):
                 return jnp.where(alive, new, old)
 
-            st2 = st2._replace(
-                # rr frozen too: an idle sub-lane is an exact state
-                # fixpoint, so a sliced run's final state matches the
-                # unbounded run's bit for bit (and rr stays congruent
-                # to cycle mod PORTS everywhere).
-                rr=keep(st2.rr, st.rr),
-                cycle=keep(st2.cycle, st.cycle),
-                st_busy=keep(st2.st_busy, st.st_busy),
-                st_exec=keep(st2.st_exec, st.st_exec),
-                st_enroute=keep(st2.st_enroute, st.st_enroute),
-                st_stall=jnp.where(alive[:, None], st2.st_stall,
-                                   st.st_stall),
-                st_hops=keep(st2.st_hops, st.st_hops),
-                st_inj=keep(st2.st_inj, st.st_inj),
-            )
+            with jax.named_scope("engine.freeze"):
+                st2 = st2._replace(
+                    # rr frozen too: an idle sub-lane is an exact state
+                    # fixpoint, so a sliced run's final state matches the
+                    # unbounded run's bit for bit (and rr stays congruent
+                    # to cycle mod PORTS everywhere).
+                    rr=keep(st2.rr, st.rr),
+                    cycle=keep(st2.cycle, st.cycle),
+                    st_busy=keep(st2.st_busy, st.st_busy),
+                    st_exec=keep(st2.st_exec, st.st_exec),
+                    st_enroute=keep(st2.st_enroute, st.st_enroute),
+                    st_stall=jnp.where(alive[:, None], st2.st_stall,
+                                       st.st_stall),
+                    st_hops=keep(st2.st_hops, st.st_hops),
+                    st_inj=keep(st2.st_inj, st.st_inj),
+                )
             if use_ff:
-                st2 = ffwd(prog, mode, geom, sub_id, budget - spent,
-                           st, st2)
+                with jax.named_scope("engine.ff_step"):
+                    st2 = ffwd(prog, mode, geom, sub_id, budget - spent,
+                               st, st2)
             return st2
 
         # budget maps like the state: one (N,) row per lane
@@ -1377,9 +1401,10 @@ def _get_engine(cfg: MachineConfig, chunk: int, n_max: int | None = None,
                 # only: both chunk bodies are bit-identical by
                 # construction, so a mid-chunk misprediction costs
                 # ticks, never correctness.
-                lone = (lone_probe(sub_ids, s)
-                        & (s.cycle < cfg.max_cycles)
-                        & (s.cycle - cycle0 < budget))
+                with jax.named_scope("engine.ff_probe"):
+                    lone = (lone_probe(sub_ids, s)
+                            & (s.cycle < cfg.max_cycles)
+                            & (s.cycle - cycle0 < budget))
                 s = jax.lax.cond(lone.any(),
                                  functools.partial(chunk_scan, step_ff),
                                  functools.partial(chunk_scan, step), s)
@@ -1389,8 +1414,10 @@ def _get_engine(cfg: MachineConfig, chunk: int, n_max: int | None = None,
             # stepped while other (sub-)lanes run (their non-stat state is
             # undefined once completed=False), and their churn must not
             # abort the healthy lanes.
-            high = (s.pend_n >= PEND_CAP - 2) & (s.cycle < cfg.max_cycles)
-            over = over | high.any(axis=1)
+            with jax.named_scope("engine.guard"):
+                high = (s.pend_n >= PEND_CAP - 2) \
+                    & (s.cycle < cfg.max_cycles)
+                over = over | high.any(axis=1)
             return s, over, it + 1
 
         over0 = jnp.zeros((st.cycle.shape[0],), jnp.bool_)
@@ -1447,14 +1474,87 @@ def _pe_slice_result(st_host: dict, done: bool, b: int,
     )
 
 
-def _host_stats(st: MachineState) -> dict:
-    """Pull the result-bearing state leaves to host numpy once."""
+def _host_stats(st: MachineState, cycle: np.ndarray | None = None) -> dict:
+    """Pull the result-bearing state leaves to host numpy once; ``cycle``
+    is the cycle counters where the caller has already read them."""
     return dict(
-        cycle=np.asarray(st.cycle), st_busy=np.asarray(st.st_busy),
+        cycle=np.asarray(st.cycle if cycle is None else cycle),
+        st_busy=np.asarray(st.st_busy),
         st_exec=np.asarray(st.st_exec), st_enroute=np.asarray(st.st_enroute),
         st_hops=np.asarray(st.st_hops), st_inj=np.asarray(st.st_inj),
         st_stall=np.asarray(st.st_stall), mem_val=np.asarray(st.mem_val),
     )
+
+
+# The engine-call counters: ``stepped`` and ``plain`` as
+# :class:`repro.core.sweep.EngineTelemetry` defines them, and the split of
+# ``stepped`` into live / finished / tail / pad PE-ticks.
+TICK_COUNTERS = ("stepped_pe_ticks", "plain_pe_ticks", "live_pe_ticks",
+                 "finished_pe_ticks", "tail_pe_ticks", "pad_pe_ticks")
+
+
+def engine_call_ticks(ticks, cycle0, cycle1, lane_rows, n_shards: int,
+                      chunk: int) -> dict:
+    """Account for every PE-tick one engine call stepped.
+
+    Host arrays in the engine's (device) lane order: ``ticks`` (B,) wall
+    ticks, uniform within each of the ``n_shards`` consecutive device
+    shards; ``cycle0`` / ``cycle1`` (B, N) each PE's cycle counter at the
+    call's start (None: all 0) and end; ``lane_rows`` (B, N) bool, the
+    rows that belong to a (sub-)lane.  Per shard, with ``adv`` a lane
+    row's cycle advance capped at the shard's ticks and ``M`` the largest
+    ``adv`` in the shard:
+
+    * ``live_pe_ticks`` = sum of ``adv``: ticks that simulated a cycle;
+    * ``finished_pe_ticks`` = sum of ``M - adv``: the row's lane had
+      finished, or was capped or halted, while others still stepped;
+    * ``tail_pe_ticks`` = sum of ``ticks - M``: the chunk granularity;
+    * ``pad_pe_ticks`` = ticks x the rows of no lane (PEs beyond a lane's
+      mesh, super-lane rows left empty, inert shard-pad lanes).
+
+    The four sum to ``stepped_pe_ticks`` exactly.  ``plain_pe_ticks`` is
+    what the tick-per-cycle engine would step to reach the same counters
+    (the largest advance rounded up to the chunk).
+    """
+    t = np.asarray(ticks, np.int64)
+    adv_all = np.asarray(cycle1, np.int64)
+    if cycle0 is not None:
+        adv_all = adv_all - np.asarray(cycle0, np.int64)
+    lane_rows = np.asarray(lane_rows, bool)
+    b, n = adv_all.shape
+    per = b // n_shards
+    out = dict.fromkeys(TICK_COUNTERS, 0)
+    for g0 in range(0, b, per):
+        g = slice(g0, g0 + per)
+        it = int(t[g0])
+        rows = lane_rows[g]
+        adv = np.minimum(adv_all[g], it)[rows]
+        m = int(adv.max(initial=0))
+        live = int(adv.sum())
+        n_rows = int(rows.sum())
+        out["stepped_pe_ticks"] += it * per * n
+        out["plain_pe_ticks"] += (-(-int(adv_all[g].max(initial=0)) // chunk)
+                                  * chunk * per * n)
+        out["live_pe_ticks"] += live
+        out["finished_pe_ticks"] += m * n_rows - live
+        out["tail_pe_ticks"] += (it - m) * n_rows
+        out["pad_pe_ticks"] += it * (per * n - n_rows)
+    return out
+
+
+def _lane_rows(workloads, lane_geoms: np.ndarray, n_max: int) -> np.ndarray:
+    """(B, N) bool in input order: the PE rows that carry a (sub-)lane —
+    each placement's rectangle of a packed batch, else each lane's own
+    width*height."""
+    rows = np.zeros((workloads.batch, n_max), bool)
+    if workloads.plan is not None:
+        for sub in workloads.plan.placements:
+            w_sup = workloads.plan.super_geoms[sub.super_lane][0]
+            rows[sub.super_lane, sub.pe_ids(w_sup)] = True
+    else:
+        for b, (w, h) in enumerate(lane_geoms):
+            rows[b, :int(w) * int(h)] = True
+    return rows
 
 
 def _validate_deadlines(deadlines, n: int) -> list:
@@ -1558,10 +1658,17 @@ def _run_many_impl(cfg: MachineConfig, workloads, *, modes=None, geoms=None,
         across every engine call this run makes (one per wave under
         ``pack=True``): ``stepped_pe_ticks`` (wall PE-steps executed),
         ``plain_pe_ticks`` (PE-steps the plain tick-per-cycle engine
-        would execute for the same final cycle counts) and
-        ``engine_calls``.  ``dead_step_fraction`` is
-        ``1 - stepped/plain`` — exactly 0 for ``fast_forward=False``
-        engines by construction.
+        would execute for the same final cycle counts), their split into
+        ``live`` / ``finished`` / ``tail`` / ``pad_pe_ticks``
+        (:func:`engine_call_ticks`) and ``engine_calls``.
+        ``dead_step_fraction`` is ``1 - stepped/plain`` — exactly 0 for
+        ``fast_forward=False`` engines by construction.
+
+    Host phases run under the spans of :mod:`repro.core.spans`:
+    ``pack.plan`` (hints, wave plan, certification) and one
+    ``sweep.wave`` per wave when packed; per engine call ``sweep.place``
+    (stacking, shard plan, budget, device placement, initial state),
+    ``engine.dispatch``, ``engine.wait`` and ``sweep.unpack``.
 
     Returns:
       One :class:`RunResult` per lane, in input order — metrics are exactly
@@ -1592,42 +1699,43 @@ def _run_many_impl(cfg: MachineConfig, workloads, *, modes=None, geoms=None,
         wls = list(workloads)
         if deadlines is not None:
             deadlines = _validate_deadlines(deadlines, len(wls))
-        if cycle_hints is not None:
-            # validate eagerly: the wave planner's homogeneous-batch
-            # shortcut can skip shard_loads, and the per-wave hint
-            # aggregation below indexes by input lane.
-            from repro.core.batch import validate_hints
-            cycle_hints = validate_hints(cycle_hints, len(wls))
-        else:
-            # No measured oracle: the static cost model supplies the
-            # planners' default load signal for heterogeneous batches
-            # (repro.analysis.estimate_cycles, replacing the
-            # inverse-mesh-area proxy).  Hints steer scheduling only;
-            # lane results are bit-identical either way.
-            from repro.core.batch import static_cycle_hints
-            cycle_hints = static_cycle_hints(wls)
-        # A sharded schedule may run up to one super-lane per device
-        # side by side without coupling their makespans, so the wave
-        # planner gets the device count as its parallel width (capped
-        # at the lane count like the shard plan itself).
-        parallel = min(len(jax.devices()), len(wls)) if shard else 1
-        batches, waves, stats = pack_schedule(wls, modes=modes,
-                                              super_geom=super_geom,
-                                              cycle_hints=cycle_hints,
-                                              parallel=parallel)
-        # Certify the isolation property co-tenancy rests on: after
-        # rebasing, no AM or meta_pe word may target a PE outside its
-        # own sub-lane rectangle (west-first routes never leave the
-        # src->dst bbox, so rectangle containment => no cross-lane
-        # traffic).  Cheap vectorized scan; catches both packer bugs
-        # and post-pack corruption before any cycle runs.
-        from repro.analysis.checks import (check_packed_batch,
-                                           raise_on_findings)
-        for wb in batches:
-            raise_on_findings(
-                check_packed_batch(wb),
-                context="packed batch failed rectangle-confinement "
-                        "certification")
+        with span("pack.plan"):
+            if cycle_hints is not None:
+                # validate eagerly: the wave planner's homogeneous-batch
+                # shortcut can skip shard_loads, and the per-wave hint
+                # aggregation below indexes by input lane.
+                from repro.core.batch import validate_hints
+                cycle_hints = validate_hints(cycle_hints, len(wls))
+            else:
+                # No measured oracle: the static cost model supplies the
+                # planners' default load signal for heterogeneous batches
+                # (repro.analysis.estimate_cycles, replacing the
+                # inverse-mesh-area proxy).  Hints steer scheduling only;
+                # lane results are bit-identical either way.
+                from repro.core.batch import static_cycle_hints
+                cycle_hints = static_cycle_hints(wls)
+            # A sharded schedule may run up to one super-lane per device
+            # side by side without coupling their makespans, so the wave
+            # planner gets the device count as its parallel width (capped
+            # at the lane count like the shard plan itself).
+            parallel = min(len(jax.devices()), len(wls)) if shard else 1
+            batches, waves, stats = pack_schedule(wls, modes=modes,
+                                                  super_geom=super_geom,
+                                                  cycle_hints=cycle_hints,
+                                                  parallel=parallel)
+            # Certify the isolation property co-tenancy rests on: after
+            # rebasing, no AM or meta_pe word may target a PE outside its
+            # own sub-lane rectangle (west-first routes never leave the
+            # src->dst bbox, so rectangle containment => no cross-lane
+            # traffic).  Cheap vectorized scan; catches both packer bugs
+            # and post-pack corruption before any cycle runs.
+            from repro.analysis.checks import (check_packed_batch,
+                                               raise_on_findings)
+            for wb in batches:
+                raise_on_findings(
+                    check_packed_batch(wb),
+                    context="packed batch failed rectangle-confinement "
+                            "certification")
         if pack_stats is not None:
             pack_stats.update(stats)
         results: list = [None] * len(wls)
@@ -1649,11 +1757,11 @@ def _run_many_impl(cfg: MachineConfig, workloads, *, modes=None, geoms=None,
             dls_w = (None if deadlines is None
                      else [deadlines[i] for i in wave])
             try:
-                wave_res = _run_many_impl(cfg, wb, chunk=chunk, shard=shard,
-                                          cycle_hints=hints_w,
-                                          shard_stats=ws,
-                                          telemetry=telemetry,
-                                          deadlines=dls_w)
+                with span("sweep.wave"):
+                    wave_res = _run_many_impl(
+                        cfg, wb, chunk=chunk, shard=shard,
+                        cycle_hints=hints_w, shard_stats=ws,
+                        telemetry=telemetry, deadlines=dls_w)
             except RuntimeError as e:
                 supers = getattr(e, "lanes", None)
                 if supers is None:
@@ -1682,222 +1790,220 @@ def _run_many_impl(cfg: MachineConfig, workloads, *, modes=None, geoms=None,
                                 for w in wave_shard_stats),
                 plan=[w["plan"] for w in wave_shard_stats])
         return results
-    if not isinstance(workloads, BatchedWorkloads):
-        workloads = list(workloads)
-        if cycle_hints is None and shard:
-            # Default the shard balancer's load signal from the static
-            # cost model (homogeneous batches included: LPT over
-            # per-lane estimates beats the uniform area proxy there).
-            from repro.core.batch import static_cycle_hints
-            cycle_hints = static_cycle_hints(workloads, geoms,
-                                             homogeneous=True)
-        workloads = stack_workloads(workloads, geoms=geoms)
-        geoms = None        # now carried on the batch
-    n_max = workloads.n_pes
-    if geoms is None:
-        geoms = workloads.geoms
-    if geoms is None:
-        # no geometry information anywhere: every lane runs on cfg's mesh,
-        # so the (unpadded) batch must have been compiled for exactly it.
-        if n_max != cfg.n_pes:
-            raise ValueError(f"batch compiled for {n_max} PEs but cfg "
-                             f"has {cfg.n_pes}")
-        lane_geoms = np.tile(np.array([[cfg.width, cfg.height]], np.int32),
-                             (workloads.batch, 1))
-    else:
-        lane_geoms = np.asarray(geoms, np.int32)
-        if lane_geoms.shape != (workloads.batch, 2):
-            raise ValueError(f"geoms shape {lane_geoms.shape} for "
-                             f"{workloads.batch} lanes (want (B, 2))")
-        if (lane_geoms[:, 0] * lane_geoms[:, 1] > n_max).any():
-            raise ValueError("lane geometry exceeds the batch PE axis "
-                             f"({n_max} PEs)")
-        if not cfg.traced_geometry:
-            if ((lane_geoms[:, 0] != cfg.width)
-                    | (lane_geoms[:, 1] != cfg.height)).any():
-                raise ValueError(
-                    "per-lane geometries differing from the config require "
-                    "cfg.traced_geometry=True (static engines bake the "
-                    "mesh into the trace)")
+    with span("sweep.place"):
+        if not isinstance(workloads, BatchedWorkloads):
+            workloads = list(workloads)
+            if cycle_hints is None and shard:
+                # Default the shard balancer's load signal from the static
+                # cost model (homogeneous batches included: LPT over
+                # per-lane estimates beats the uniform area proxy there).
+                from repro.core.batch import static_cycle_hints
+                cycle_hints = static_cycle_hints(workloads, geoms,
+                                                 homogeneous=True)
+            workloads = stack_workloads(workloads, geoms=geoms)
+            geoms = None        # now carried on the batch
+        n_max = workloads.n_pes
+        if geoms is None:
+            geoms = workloads.geoms
+        if geoms is None:
+            # no geometry information anywhere: every lane runs on cfg's mesh,
+            # so the (unpadded) batch must have been compiled for exactly it.
             if n_max != cfg.n_pes:
-                raise ValueError(f"batch padded to {n_max} PEs but the "
-                                 f"static-geometry cfg has {cfg.n_pes}")
-    if workloads.mem_words > cfg.mem_words:
-        cfg = dataclasses.replace(cfg, mem_words=workloads.mem_words)
-
-    if modes is None:
-        modes = workloads.modes
-    if modes is None:
-        lane_modes = np.full((workloads.batch,), mode_code(cfg), np.int32)
-    else:
-        lane_modes = np.asarray([resolve_mode(m) for m in modes], np.int32)
-        if lane_modes.shape[0] != workloads.batch:
-            raise ValueError(f"{lane_modes.shape[0]} modes for "
-                             f"{workloads.batch} lanes")
-    if not cfg.traced_modes and (lane_modes != mode_code(cfg)).any():
-        raise ValueError("per-lane modes differing from the config flags "
-                         "require cfg.traced_modes=True (static engines "
-                         "bake the mode into the trace)")
-
-    if workloads.sub_ids is not None:
-        sub_ids = np.asarray(workloads.sub_ids, np.int32)
-        local_ids = np.asarray(workloads.local_ids, np.int32)
-    else:
-        sub_ids = np.zeros((workloads.batch, n_max), np.int32)
-        local_ids = np.tile(np.arange(n_max, dtype=np.int32),
-                            (workloads.batch, 1))
-
-    if cycle_hints is not None:
-        # validate regardless of device count: a malformed hints list
-        # must fail identically on a 1-device laptop and the forced-
-        # multi-device CI job (plan_shards only runs on the latter).
-        from repro.core.batch import validate_hints
-        cycle_hints = validate_hints(cycle_hints, workloads.batch)
-
-    # --- per-PE cycle budget (deadlines) ------------------------------
-    # The engine's budget argument is (B, N) int32: INT32_MAX everywhere
-    # by default, a lane's own deadline on its rows otherwise.  Packed
-    # batches map each deadline onto its sub-lane rectangle, so a
-    # deadline-frozen sub-lane never stalls its co-tenants.
-    budget = unbounded_budget(workloads.batch, n_max)
-    if deadlines is not None:
-        if workloads.plan is not None:
-            deadlines = _validate_deadlines(
-                deadlines, len(workloads.plan.placements))
-            for sub in workloads.plan.placements:
-                dl = deadlines[sub.lane]
-                if dl is not None:
-                    w_sup = workloads.plan.super_geoms[sub.super_lane][0]
-                    budget[sub.super_lane, sub.pe_ids(w_sup)] = dl
+                raise ValueError(f"batch compiled for {n_max} PEs but cfg "
+                                 f"has {cfg.n_pes}")
+            lane_geoms = np.tile(np.array([[cfg.width, cfg.height]], np.int32),
+                                 (workloads.batch, 1))
         else:
-            deadlines = _validate_deadlines(deadlines, workloads.batch)
-            for b, dl in enumerate(deadlines):
-                if dl is not None:
-                    budget[b, :] = dl
+            lane_geoms = np.asarray(geoms, np.int32)
+            if lane_geoms.shape != (workloads.batch, 2):
+                raise ValueError(f"geoms shape {lane_geoms.shape} for "
+                                 f"{workloads.batch} lanes (want (B, 2))")
+            if (lane_geoms[:, 0] * lane_geoms[:, 1] > n_max).any():
+                raise ValueError("lane geometry exceeds the batch PE axis "
+                                 f"({n_max} PEs)")
+            if not cfg.traced_geometry:
+                if ((lane_geoms[:, 0] != cfg.width)
+                        | (lane_geoms[:, 1] != cfg.height)).any():
+                    raise ValueError(
+                        "per-lane geometries differing from the config require "
+                        "cfg.traced_geometry=True (static engines bake the "
+                        "mesh into the trace)")
+                if n_max != cfg.n_pes:
+                    raise ValueError(f"batch padded to {n_max} PEs but the "
+                                     f"static-geometry cfg has {cfg.n_pes}")
+        if workloads.mem_words > cfg.mem_words:
+            cfg = dataclasses.replace(cfg, mem_words=workloads.mem_words)
 
-    # --- lane-axis device sharding ------------------------------------
-    # Lanes never interact, so the batch shards freely over devices: the
-    # plan balances real lanes by runtime estimate, the lane arrays are
-    # gathered into device-major order (inert all-zero 1x1 lanes — idle
-    # at cycle 0 — pad B to a multiple of the device count), and results
-    # are gathered back to input order below.  One device (or shard
-    # off): the plain engine, identical cache entry.  The device count
-    # is capped at the batch size — a device below one real lane could
-    # only step inert pads (and hosts that force absurd device counts,
-    # e.g. the 512 fake host devices repro.launch.dryrun installs for
-    # the LLM dry-runs, must not explode a small sweep into a 512-lane
-    # mesh).
-    n_dev = min(len(jax.devices()), workloads.batch) if shard else 1
-    order = inv = None
-    if shard and n_dev > 1:
-        from repro.core.batch import plan_shards, shard_loads
-        geom_list = [tuple(g) for g in lane_geoms]
-        loads = cycle_hints
-        if loads is None:
-            # the inverse-area proxy calls a 1x1 mesh the LONGEST lane,
-            # but a lane with nothing to inject (e.g. a wave-padding
-            # inert lane) is idle at cycle 0 — zero its load so the
-            # balancer spreads the real work instead.
-            work = np.asarray(workloads.amq_len).sum(axis=1)
-            loads = [0.0 if w == 0 else l
-                     for w, l in zip(work, shard_loads(geom_list))]
-        dev_plan = plan_shards(geom_list, n_dev, cycle_hints=loads)
-        order = [i for dev in dev_plan for i in dev]
-        inv = np.empty((workloads.batch,), np.int64)
-        for pos, lane in enumerate(order):
-            if lane >= 0:
-                inv[lane] = pos
-    if shard_stats is not None:
-        shard_stats.update(
-            n_devices=n_dev,
-            lanes_per_device=(len(order) // n_dev if order is not None
-                             else workloads.batch),
-            n_pad_lanes=(len(order) - workloads.batch
-                         if order is not None else 0),
-            plan=(dev_plan if order is not None
-                  else [list(range(workloads.batch))]))
+        if modes is None:
+            modes = workloads.modes
+        if modes is None:
+            lane_modes = np.full((workloads.batch,), mode_code(cfg), np.int32)
+        else:
+            lane_modes = np.asarray([resolve_mode(m) for m in modes], np.int32)
+            if lane_modes.shape[0] != workloads.batch:
+                raise ValueError(f"{lane_modes.shape[0]} modes for "
+                                 f"{workloads.batch} lanes")
+        if not cfg.traced_modes and (lane_modes != mode_code(cfg)).any():
+            raise ValueError("per-lane modes differing from the config flags "
+                             "require cfg.traced_modes=True (static engines "
+                             "bake the mode into the trace)")
 
-    # sharded: every lane array goes straight to the device that runs
-    # its lanes (device-major order matches the lane mesh's split)
-    sharding = lane_sharding(n_dev) if order is not None else None
+        if workloads.sub_ids is not None:
+            sub_ids = np.asarray(workloads.sub_ids, np.int32)
+            local_ids = np.asarray(workloads.local_ids, np.int32)
+        else:
+            sub_ids = np.zeros((workloads.batch, n_max), np.int32)
+            local_ids = np.tile(np.arange(n_max, dtype=np.int32),
+                                (workloads.batch, 1))
 
-    def lanes(a, pad_row=None):
-        a = np.asarray(a, np.int32)
-        if order is None:
-            return jnp.asarray(a)
-        out = np.zeros((len(order),) + a.shape[1:], np.int32)
-        for pos, lane in enumerate(order):
-            if lane >= 0:
-                out[pos] = a[lane]
-            elif pad_row is not None:
-                out[pos] = pad_row
-        return jax.device_put(out, sharding)
+        if cycle_hints is not None:
+            # validate regardless of device count: a malformed hints list
+            # must fail identically on a 1-device laptop and the forced-
+            # multi-device CI job (plan_shards only runs on the latter).
+            from repro.core.batch import validate_hints
+            cycle_hints = validate_hints(cycle_hints, workloads.batch)
 
-    st = init_lanes(cfg, lanes(workloads.static_ams),
-                    lanes(workloads.amq_len), lanes(workloads.mem_val),
-                    lanes(workloads.mem_meta), sharding=sharding)
-    engine = _get_engine(cfg, chunk, n_max,
-                         n_devices=n_dev if order is not None else 1)
-    st, over, idle, ticks = engine(
-        lanes(workloads.prog), lanes(lane_modes),
-        lanes(lane_geoms, pad_row=np.array([1, 1], np.int32)),
-        lanes(sub_ids),
-        lanes(local_ids, pad_row=np.arange(n_max, dtype=np.int32)), st,
-        lanes(budget, pad_row=np.full((n_max,), int(ENGINE_UNBOUNDED),
-                                      np.int32)))
-    if telemetry is not None:
-        # dead-step accounting (device order; ticks is uniform per device
-        # shard): wall PE-steps actually executed vs what the plain
-        # tick-per-cycle engine would have executed to reach the same
-        # final cycle counts (rounded up to chunk granularity, which is
-        # exactly what the plain engine runs).
-        t_np = np.asarray(ticks)
-        cyc_np = np.asarray(st.cycle)
-        bsz = t_np.shape[0]
-        per_dev = bsz // n_dev if order is not None else bsz
-        groups = [list(range(g, g + per_dev)) for g in range(0, bsz, per_dev)]
-        stepped = plain = 0
-        for g in groups:
-            it_ticks = int(t_np[g[0]])
-            want = int(cyc_np[g].max())
-            stepped += it_ticks * len(g) * n_max
-            plain += -(-want // chunk) * chunk * len(g) * n_max
-        telemetry["stepped_pe_ticks"] = (
-            telemetry.get("stepped_pe_ticks", 0) + stepped)
-        telemetry["plain_pe_ticks"] = (
-            telemetry.get("plain_pe_ticks", 0) + plain)
-        telemetry["engine_calls"] = telemetry.get("engine_calls", 0) + 1
-    over = np.asarray(over)
-    idle = np.asarray(idle)                      # (B, N) per-PE group idle
-    host = _host_stats(st)
-    if inv is not None:
-        # gather back to input-lane order (drops the inert pad lanes):
-        # every downstream consumer — overflow naming, plan un-packing,
-        # per-lane slicing — indexes by input lane again.
-        over = over[inv]
-        idle = idle[inv]
-        host = {k: v[inv] for k, v in host.items()}
-    if over.any():
-        bad = np.nonzero(over)[0].tolist()
-        err = RuntimeError("pending-FIFO overflow: consumption guarantee "
-                           f"violated (simulator invariant; lanes {bad})")
-        err.lanes = bad  # structured, so pack=True can name input lanes
-        raise err
-    if workloads.plan is not None:
-        # un-pack: one result per ORIGINAL lane, gathered from its
-        # sub-mesh rectangle (plan order is input order by construction).
-        out = []
-        for sub in workloads.plan.placements:
-            w_sup = workloads.plan.super_geoms[sub.super_lane][0]
-            ids = sub.pe_ids(w_sup)
-            out.append(_pe_slice_result(
-                host, bool(idle[sub.super_lane, ids[0]]),
-                sub.super_lane, ids))
-        return out
-    return [_pe_slice_result(
-        host, bool(idle[b, 0]), b,
-        np.arange(int(lane_geoms[b, 0] * lane_geoms[b, 1])))
-            for b in range(workloads.batch)]
+        # --- per-PE cycle budget (deadlines) ------------------------------
+        # The engine's budget argument is (B, N) int32: INT32_MAX everywhere
+        # by default, a lane's own deadline on its rows otherwise.  Packed
+        # batches map each deadline onto its sub-lane rectangle, so a
+        # deadline-frozen sub-lane never stalls its co-tenants.
+        budget = unbounded_budget(workloads.batch, n_max)
+        if deadlines is not None:
+            if workloads.plan is not None:
+                deadlines = _validate_deadlines(
+                    deadlines, len(workloads.plan.placements))
+                for sub in workloads.plan.placements:
+                    dl = deadlines[sub.lane]
+                    if dl is not None:
+                        w_sup = workloads.plan.super_geoms[sub.super_lane][0]
+                        budget[sub.super_lane, sub.pe_ids(w_sup)] = dl
+            else:
+                deadlines = _validate_deadlines(deadlines, workloads.batch)
+                for b, dl in enumerate(deadlines):
+                    if dl is not None:
+                        budget[b, :] = dl
+
+        # --- lane-axis device sharding ------------------------------------
+        # Lanes never interact, so the batch shards freely over devices: the
+        # plan balances real lanes by runtime estimate, the lane arrays are
+        # gathered into device-major order (inert all-zero 1x1 lanes — idle
+        # at cycle 0 — pad B to a multiple of the device count), and results
+        # are gathered back to input order below.  One device (or shard
+        # off): the plain engine, identical cache entry.  The device count
+        # is capped at the batch size — a device below one real lane could
+        # only step inert pads (and hosts that force absurd device counts,
+        # e.g. the 512 fake host devices repro.launch.dryrun installs for
+        # the LLM dry-runs, must not explode a small sweep into a 512-lane
+        # mesh).
+        n_dev = min(len(jax.devices()), workloads.batch) if shard else 1
+        order = inv = None
+        if shard and n_dev > 1:
+            from repro.core.batch import plan_shards, shard_loads
+            geom_list = [tuple(g) for g in lane_geoms]
+            loads = cycle_hints
+            if loads is None:
+                # the inverse-area proxy calls a 1x1 mesh the LONGEST lane,
+                # but a lane with nothing to inject (e.g. a wave-padding
+                # inert lane) is idle at cycle 0 — zero its load so the
+                # balancer spreads the real work instead.
+                work = np.asarray(workloads.amq_len).sum(axis=1)
+                loads = [0.0 if w == 0 else l
+                         for w, l in zip(work, shard_loads(geom_list))]
+            dev_plan = plan_shards(geom_list, n_dev, cycle_hints=loads)
+            order = [i for dev in dev_plan for i in dev]
+            inv = np.empty((workloads.batch,), np.int64)
+            for pos, lane in enumerate(order):
+                if lane >= 0:
+                    inv[lane] = pos
+        if shard_stats is not None:
+            shard_stats.update(
+                n_devices=n_dev,
+                lanes_per_device=(len(order) // n_dev if order is not None
+                                 else workloads.batch),
+                n_pad_lanes=(len(order) - workloads.batch
+                             if order is not None else 0),
+                plan=(dev_plan if order is not None
+                      else [list(range(workloads.batch))]))
+
+        # sharded: every lane array goes straight to the device that runs
+        # its lanes (device-major order matches the lane mesh's split)
+        sharding = lane_sharding(n_dev) if order is not None else None
+
+        def lanes(a, pad_row=None):
+            a = np.asarray(a, np.int32)
+            if order is None:
+                return jnp.asarray(a)
+            out = np.zeros((len(order),) + a.shape[1:], np.int32)
+            for pos, lane in enumerate(order):
+                if lane >= 0:
+                    out[pos] = a[lane]
+                elif pad_row is not None:
+                    out[pos] = pad_row
+            return jax.device_put(out, sharding)
+
+        st = init_lanes(cfg, lanes(workloads.static_ams),
+                        lanes(workloads.amq_len), lanes(workloads.mem_val),
+                        lanes(workloads.mem_meta), sharding=sharding)
+        args = (lanes(workloads.prog), lanes(lane_modes),
+                lanes(lane_geoms, pad_row=np.array([1, 1], np.int32)),
+                lanes(sub_ids),
+                lanes(local_ids, pad_row=np.arange(n_max, dtype=np.int32)),
+                st,
+                lanes(budget, pad_row=np.full(
+                    (n_max,), int(ENGINE_UNBOUNDED), np.int32)))
+    with span("engine.dispatch"):
+        engine = _get_engine(cfg, chunk, n_max,
+                             n_devices=n_dev if order is not None else 1)
+        outs = engine(*args)
+    with span("engine.wait"):
+        st, over, idle, ticks = jax.block_until_ready(outs)
+    with span("sweep.unpack"):
+        host = _host_stats(st)
+        if telemetry is not None:
+            # every stepped PE-tick of this call, in device order (ticks
+            # is uniform per device shard); cycles start at 0 here
+            rows = _lane_rows(workloads, lane_geoms, n_max)
+            if inv is not None:
+                rows_dev = np.zeros((len(order), n_max), bool)
+                rows_dev[inv] = rows
+                rows = rows_dev
+            acc = engine_call_ticks(
+                np.asarray(ticks), None, host["cycle"], rows,
+                n_dev if order is not None else 1, chunk)
+            for k, v in acc.items():
+                telemetry[k] = telemetry.get(k, 0) + v
+            telemetry["engine_calls"] = telemetry.get("engine_calls", 0) + 1
+        over = np.asarray(over)
+        idle = np.asarray(idle)                  # (B, N) per-PE group idle
+        if inv is not None:
+            # gather back to input-lane order (drops the inert pad lanes):
+            # every downstream consumer — overflow naming, plan un-packing,
+            # per-lane slicing — indexes by input lane again.
+            over = over[inv]
+            idle = idle[inv]
+            host = {k: v[inv] for k, v in host.items()}
+        if over.any():
+            bad = np.nonzero(over)[0].tolist()
+            err = RuntimeError("pending-FIFO overflow: consumption guarantee "
+                               f"violated (simulator invariant; lanes {bad})")
+            err.lanes = bad  # structured, so pack=True can name input lanes
+            raise err
+        if workloads.plan is not None:
+            # un-pack: one result per ORIGINAL lane, gathered from its
+            # sub-mesh rectangle (plan order is input order by construction).
+            out = []
+            for sub in workloads.plan.placements:
+                w_sup = workloads.plan.super_geoms[sub.super_lane][0]
+                ids = sub.pe_ids(w_sup)
+                out.append(_pe_slice_result(
+                    host, bool(idle[sub.super_lane, ids[0]]),
+                    sub.super_lane, ids))
+            return out
+        return [_pe_slice_result(
+            host, bool(idle[b, 0]), b,
+            np.arange(int(lane_geoms[b, 0] * lane_geoms[b, 1])))
+                for b in range(workloads.batch)]
 
 
 def run_many(cfg: MachineConfig, workloads, *, modes=None, geoms=None,

@@ -29,6 +29,7 @@ import dataclasses
 
 from repro.core import machine
 from repro.core.machine import MachineConfig, RunResult
+from repro.core.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,10 +173,22 @@ class EngineTelemetry:
     fraction of plain PE-steps the fast-forward engine skipped (0.0 by
     construction on plain engines, and on workloads with no compressible
     lone-flight stretches).
+
+    The other four split ``stepped_pe_ticks`` exactly
+    (:func:`repro.core.machine.engine_call_ticks`): ``live_pe_ticks``
+    simulated a cycle of a (sub-)lane; ``finished_pe_ticks`` stepped a
+    lane that had finished, or was capped or halted, while others in its
+    device shard still ran; ``tail_pe_ticks`` ran past the shard's last
+    simulated cycle to the end of the chunk; ``pad_pe_ticks`` stepped
+    rows of no lane.
     """
     stepped_pe_ticks: int
     plain_pe_ticks: int
     engine_calls: int
+    live_pe_ticks: int = 0
+    finished_pe_ticks: int = 0
+    tail_pe_ticks: int = 0
+    pad_pe_ticks: int = 0
 
     @property
     def dead_step_fraction(self) -> float:
@@ -187,7 +200,11 @@ class EngineTelemetry:
         return dict(stepped_pe_ticks=int(self.stepped_pe_ticks),
                     plain_pe_ticks=int(self.plain_pe_ticks),
                     engine_calls=int(self.engine_calls),
-                    dead_step_fraction=float(self.dead_step_fraction))
+                    dead_step_fraction=float(self.dead_step_fraction),
+                    live_pe_ticks=int(self.live_pe_ticks),
+                    finished_pe_ticks=int(self.finished_pe_ticks),
+                    tail_pe_ticks=int(self.tail_pe_ticks),
+                    pad_pe_ticks=int(self.pad_pe_ticks))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,9 +275,10 @@ def sweep(cfg: MachineConfig, request: SweepRequest) -> SweepReport:
         # malformed lanes here, with per-lane diagnostics, instead of
         # letting them poison a shared fabric at runtime.
         from repro.analysis import validate_request
-        validate_request(wls, modes=request.modes,
-                         strict=(request.validate == "strict"),
-                         stream_wait_cap=cfg.stream_wait_cap)
+        with span("sweep.validate"):
+            validate_request(wls, modes=request.modes,
+                             strict=(request.validate == "strict"),
+                             stream_wait_cap=cfg.stream_wait_cap)
     tm: dict = {}
     results = machine._run_many_impl(
         cfg, wls,
@@ -283,8 +301,7 @@ def sweep(cfg: MachineConfig, request: SweepRequest) -> SweepReport:
         n_devices=ss["n_devices"], lanes_per_device=ss["lanes_per_device"],
         n_pad_lanes=ss["n_pad_lanes"], plan=tuple(ss.get("plan", ())))
     telemetry = EngineTelemetry(
-        stepped_pe_ticks=tm.get("stepped_pe_ticks", 0),
-        plain_pe_ticks=tm.get("plain_pe_ticks", 0),
-        engine_calls=tm.get("engine_calls", 0))
+        engine_calls=tm.get("engine_calls", 0),
+        **{k: tm.get(k, 0) for k in machine.TICK_COUNTERS})
     return SweepReport(lanes=tuple(results), pack=pack, shard=shard,
                        telemetry=telemetry)

@@ -71,8 +71,9 @@ import numpy as np
 
 from repro.core.am import C_NEXT_PC
 from repro.core.batch import RectPool, SubLane, _rebase_into_super, bucket
-from repro.core.machine import (MachineConfig, MachineState, RunResult,
-                                _get_engine, _host_stats, _pe_slice_result,
+from repro.core.machine import (TICK_COUNTERS, MachineConfig, MachineState,
+                                RunResult, _get_engine, _host_stats,
+                                _pe_slice_result, engine_call_ticks,
                                 init_lanes, lane_sharding, mode_code,
                                 resolve_mode)
 
@@ -303,8 +304,8 @@ class SweepService:
         self.stats = dict(n_installs=0, n_refills=0, n_retired=0,
                           n_slices=0, occupancy_sum=0.0, engine_ticks=0,
                           n_retries=0, n_restarts=0, n_deadline_failures=0,
-                          n_checkpoints=0, stepped_pe_ticks=0,
-                          plain_pe_ticks=0)
+                          n_checkpoints=0,
+                          **dict.fromkeys(TICK_COUNTERS, 0))
 
         self._ckpt = None
         self._ckpt_every = int(checkpoint_every)
@@ -480,9 +481,8 @@ class SweepService:
         (dead-step accounting across every slice so far)."""
         from repro.core.sweep import EngineTelemetry
         return EngineTelemetry(
-            stepped_pe_ticks=int(self.stats["stepped_pe_ticks"]),
-            plain_pe_ticks=int(self.stats["plain_pe_ticks"]),
-            engine_calls=int(self.stats["n_slices"]))
+            engine_calls=int(self.stats["n_slices"]),
+            **{k: int(self.stats[k]) for k in TICK_COUNTERS})
 
     @property
     def futures(self) -> dict[int, Future]:
@@ -784,18 +784,15 @@ class SweepService:
         b, n = self._sub_ids.shape
         self.stats["occupancy_sum"] += (
             sum(p.used_area() for p in self._pools) / float(b * n))
-        # dead-step telemetry (the service-side mirror of run_many's):
-        # wall PE-steps actually executed vs what the plain engine would
-        # run to retire this slice's cycle deltas, per device shard.
-        per_dev = b // self._n_dev
-        stepped = plain = 0
-        for g0 in range(0, b, per_dev):
-            g = slice(g0, g0 + per_dev)
-            want = int((cyc[g] - self._cycle_host[g]).max(initial=0))
-            stepped += int(t_np[g0]) * per_dev * n
-            plain += -(-want // self._chunk) * self._chunk * per_dev * n
-        self.stats["stepped_pe_ticks"] += stepped
-        self.stats["plain_pe_ticks"] += plain
+        # every PE-tick of this slice, against the cycle counters it
+        # started from (the same accounting as run_many's)
+        rows = np.zeros((b, n), bool)
+        for r in self._residents.values():
+            rows[r.super_idx, r.ids] = True
+        acc = engine_call_ticks(t_np, self._cycle_host, cyc, rows,
+                                self._n_dev, self._chunk)
+        for k, v in acc.items():
+            self.stats[k] += v
         # writable copy: installs zero their rows in place
         self._cycle_host = np.array(cyc, np.int32)
         self._fire_hook("post_slice")
@@ -938,7 +935,7 @@ class SweepService:
         # the result-bearing leaves (memory image included) only cross to
         # host when something actually retires; a pure-compute slice costs
         # one small (b, n) cycle/idle sync.
-        host = _host_stats(st)
+        host = _host_stats(st, cycle)
         # resolve the futures BEFORE removing the residents: drain()
         # unblocks on empty pending+residents, and must never observe an
         # "all drained" state while a result is still unset.
