@@ -377,6 +377,42 @@ def _rotate_dsts(msg: jnp.ndarray) -> jnp.ndarray:
     return msg
 
 
+def _fifo_compact(buf: jnp.ndarray, keep: jnp.ndarray) -> jnp.ndarray:
+    """Stable compaction of input-port FIFOs.
+
+    buf: (..., D, F) slots, keep: (..., D) bool.  The kept slots move to
+    the front in their order and the freed tail reads 0.  Output slot j
+    takes the source slot k >= j whose rank among the kept slots is j: a
+    select network over the static depth, with no sort, gather or scatter
+    (those walk the array element by element on the chip).
+    """
+    depth = keep.shape[-1]
+    rank = jnp.cumsum(keep, axis=-1) - 1
+    slots = []
+    for j in range(depth):
+        out = jnp.zeros_like(buf[..., j, :])
+        for k in range(j, depth):
+            hit = keep[..., k] & (rank[..., k] == j)
+            out = jnp.where(hit[..., None], buf[..., k, :], out)
+        slots.append(out)
+    return jnp.stack(slots, axis=-2)
+
+
+def _fifo_set(buf: jnp.ndarray, port, slot, msg: jnp.ndarray,
+              on: jnp.ndarray) -> jnp.ndarray:
+    """``buf.at[pe, port, slot].set(msg)`` on every PE where ``on``.
+
+    buf: (N, P, D, F) FIFOs; port, slot: (N,) int arrays or Python ints;
+    msg: (N, F); on: (N,) bool.  A select against the static port and
+    slot indices, not a scatter.
+    """
+    _, ports, depth, _ = buf.shape
+    at = (on[:, None, None]
+          & (jnp.asarray(port)[..., None, None] == jnp.arange(ports)[:, None])
+          & (jnp.asarray(slot)[..., None, None] == jnp.arange(depth)))
+    return jnp.where(at[..., None], msg[:, None, None, :], buf)
+
+
 def _anchor_tia(nxt: jnp.ndarray, pe_ids: jnp.ndarray) -> jnp.ndarray:
     """TIA semantics (§2.2): compute is *anchored* with the data.
 
@@ -892,14 +928,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             removed = sel_exec3 | (grants[:, :, None]
                                    & (jnp.arange(DEPTH) == 0)[None, None, :])
             keep = slot_v & ~removed                              # (N,5,D)
-            order = jnp.argsort(
-                jnp.where(keep, jnp.arange(DEPTH)[None, None, :], DEPTH + 1),
-                axis=2)                                           # kept first
-            buf = jnp.take_along_axis(
-                st.buf, order[..., None].repeat(MSG_F, 3), axis=2)
-            buf = jnp.where(
-                (jnp.arange(DEPTH)[None, None, :] < keep.sum(2)[..., None])
-                [..., None], buf, 0)
+            buf = _fifo_compact(st.buf, keep)
             buf_n = keep.sum(axis=2).astype(jnp.int32)
             # clear reached Valiant waypoints in-place on remaining heads.
             popped0 = removed[:, :, 0]
@@ -908,9 +937,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             # in-place interception write-back: the transformed message replaces
             # the (un-removed, un-granted) head and routes onward next cycle.
             icept_port = jnp.argmax(sel_icept, axis=1)      # (N,)
-            cur_head = buf[pe_ids, icept_port, 0, :]
-            buf = buf.at[pe_ids, icept_port, 0, :].set(
-                jnp.where(was_icept[:, None], nxt_a, cur_head))
+            buf = _fifo_set(buf, icept_port, 0, nxt_a, was_icept)
 
             # transfers: sender-side view — the message leaving each PE through
             # each directional output port.
@@ -931,9 +958,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
                 m_in = send_m[jnp.clip(s, 0), o, :]
                 m_in = m_in.at[:, F_HOPS].add(1)
                 pos_d = jnp.clip(buf_n[:, q], 0, DEPTH - 1)
-                cur = buf[pe_ids, q, pos_d, :]
-                buf = buf.at[pe_ids, q, pos_d, :].set(
-                    jnp.where(has[:, None], m_in, cur))
+                buf = _fifo_set(buf, q, pos_d, m_in, has)
                 buf_n = buf_n.at[:, q].add(has.astype(jnp.int32))
 
         # ==================== INJECTION (AM NIC, §3.3.1) ====================
@@ -986,9 +1011,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             do_inj = inj_dyn | inj_stat
             net_inj = do_inj
             posi = jnp.clip(buf_n[:, P_INJ], 0, DEPTH - 1)
-            buf = jax.vmap(
-                lambda b, i, v, m: jnp.where(m, b.at[P_INJ, i].set(v), b)
-            )(buf, posi, inj_msg, net_inj)
+            buf = _fifo_set(buf, P_INJ, posi, inj_msg, net_inj)
             buf_n = buf_n.at[:, P_INJ].add(net_inj.astype(jnp.int32))
             # consume sources
             pend_h = (pend_h + inj_dyn.astype(jnp.int32)) % PEND_CAP

@@ -8,6 +8,9 @@ temporaries).  The topology is described inside a fixture — never while
 a module is imported — because only one process at a time may load the
 TPU library; keep every such compile in this one file.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -92,21 +95,70 @@ def _fits_one_chip(compiled):
     return mem
 
 
-@pytest.mark.parametrize("fast_forward", [True, False],
-                         ids=["fast_forward", "plain"])
-def test_engine_compiles_for_v5e(fig17, one_chip, no_compile_cache,
-                                 fast_forward):
+@pytest.fixture(scope="module")
+def v5e_engine(fig17, one_chip, no_compile_cache):
+    """``compiled(fast_forward) -> (compiled engine, args)`` for the
+    grid's first packed wave on one v5e, each compiled once per module."""
     import dataclasses
     cfg, lanes = fig17
-    cfg = dataclasses.replace(cfg, fast_forward=fast_forward)
     wb = _wave(lanes)
     assert wb.n_pes == 64
-    engine = machine._get_engine(cfg, CHUNK, wb.n_pes)
-    compiled = engine.lower(*_engine_args(cfg, wb, one_chip)).compile()
+    done = {}
+
+    def compiled(fast_forward):
+        if fast_forward not in done:
+            c = dataclasses.replace(cfg, fast_forward=fast_forward)
+            engine = machine._get_engine(c, CHUNK, wb.n_pes)
+            args = _engine_args(c, wb, one_chip)
+            done[fast_forward] = engine.lower(*args).compile(), args
+        return done[fast_forward]
+    return compiled
+
+
+@pytest.mark.parametrize("fast_forward", [True, False],
+                         ids=["fast_forward", "plain"])
+def test_engine_compiles_for_v5e(v5e_engine, fast_forward):
+    compiled, _ = v5e_engine(fast_forward)
     # the donated machine state is updated in place
     assert _fits_one_chip(compiled).alias_size_in_bytes > 0
     # fast-forward is one real branch (lax.cond) around the chunk scan
     assert ("conditional" in compiled.as_text()) == fast_forward
+
+
+_HLO_DEF = re.compile(r"%([\w.\-]+) = \w+\[([\d,]*)\]")
+_HLO_GATHER_SCATTER = re.compile(
+    r"%([\w.\-]+) = [^=\n]*?\b(?:gather|scatter)\(([^)]*)\)")
+
+
+def _gathers_scatters_touching(hlo, tail, size):
+    """Names of the gathers and scatters in ``hlo`` whose result or an
+    operand has trailing dims ``tail`` or ``size`` elements in all (a
+    fusion may flatten the array)."""
+    shapes = {m.group(1): tuple(int(d) for d in m.group(2).split(",") if d)
+              for m in _HLO_DEF.finditer(hlo)}
+
+    def hit(name):
+        s = shapes.get(name)
+        return s is not None and (s[-len(tail):] == tail
+                                  or math.prod(s) == size)
+    return [m.group(1) for m in _HLO_GATHER_SCATTER.finditer(hlo)
+            if any(hit(x) for x in
+                   [m.group(1)] + re.findall(r"%([\w.\-]+)", m.group(2)))]
+
+
+@pytest.mark.parametrize("fast_forward", [True, False],
+                         ids=["fast_forward", "plain"])
+def test_engine_updates_fifos_without_sort_gather_scatter(v5e_engine,
+                                                          fast_forward):
+    """The input-port FIFOs ``buf`` (lanes, PEs, 5, DEPTH, MSG_F) are
+    compacted and written with selects: on the chip a sort, gather or
+    scatter over them walks every element, once per simulated cycle."""
+    compiled, args = v5e_engine(fast_forward)
+    hlo = compiled.as_text()
+    assert "sort(" not in hlo
+    buf = args[5].buf.shape
+    assert buf[2:] == (machine.PORTS, machine.DEPTH, machine.MSG_F)
+    assert _gathers_scatters_touching(hlo, buf[2:], math.prod(buf)) == []
 
 
 def test_service_install_compiles_for_v5e(fig17, one_chip, no_compile_cache):
