@@ -5,13 +5,29 @@ file of its own, found by the name ``BENCHMARK.json`` gives it:
 
 * ``configs/<config>.json`` (the path in the configuration's ``file``),
   whose lanes name workload kinds in ``kinds/<kind>.py``;
-* ``traffic/<mix>.json``, read by :mod:`chipbench.traffic`;
+* ``traffic/<mix>.json``, read by :mod:`chipbench.traffic`, whose
+  ``loop`` chooses the client that sends it;
 * ``metrics/<metric>.py``, each a ``read(ctx)`` that returns a number, or
   None when the run holds nothing for it to read.
 
+The mix keys, and the client that reads each:
+
+* ``loop``: ``"closed"`` (the default) gives :class:`ClosedSweep`, one
+  client sending the whole grid as a blocking ``sweep()`` back to back;
+  ``"service"`` gives :class:`chipbench.service.OpenService`, single
+  lanes due on an open-loop schedule, submitted to one resident
+  ``SweepService``;
+* ``pack`` (closed): the request's sub-mesh packing switch;
+* ``shard`` (closed): split the lanes of each engine call over every
+  chip of the host;
+* ``rate``, ``n_supers``, ``chunk``, ``slice_chunks`` (service): lanes
+  due per second, and the service's resident super-lanes, cycles per
+  engine chunk and chunks per scheduler slice;
+* ``trace_seconds`` (both): the window of a ``--trace 1`` run.
+
 A run (``python chipbench/run.py --workload <cell> --seed <n> --seconds
-<s> --trace <0|1>``) generates the data from the seed, warms up with one
-request of the cell's own shapes (counted as set-up), measures the window
+<s> --trace <0|1>``) generates the data from the seed, warms up every
+program of the cell's own shapes (counted as set-up), measures the window
 with the profiler off (``--trace 0``: the cell's end-to-end metrics) or on
 (``--trace 1``: its per-layer metrics), and then compares every lane the
 window issued with the plain reference, and its simulated statistics with
@@ -146,6 +162,8 @@ class Lane:
     wl: object = None          # the compiled workload
     result: object = None      # its RunResult, once it came
     error: str | None = None
+    t_due: float | None = None   # open loop: when it was due (host clock)
+    t_done: float | None = None  # and when its future resolved
 
 
 @dataclasses.dataclass
@@ -160,6 +178,8 @@ class Window:
     stepped_pe_ticks: int      # engine PE-steps taken inside the window
     n_devices: int
     trace: object = None       # chipbench.trace.Trace when traced
+    stats_open: dict | None = None    # SweepService.stats at the open
+    stats_close: dict | None = None   # and once the last lane resolved
 
     @property
     def window_s(self) -> float:
@@ -185,6 +205,7 @@ class ClosedSweep:
                 report = sweep(g.run_cfg, SweepRequest(
                     workloads=wls, modes=[p.mode for p in g.points],
                     pack=bool(self.mix.get("pack", False)),
+                    shard=bool(self.mix.get("shard", False)),
                     deadlines=(None if deadline is None
                                else [deadline] * len(wls))))
         t1 = time.perf_counter()
@@ -218,6 +239,17 @@ class ClosedSweep:
         ticks = sum(r.telemetry.stepped_pe_ticks for _, _, r in requests)
         return dict(t_close=t_close, lanes=lanes, requests=requests,
                     stepped_pe_ticks=ticks)
+
+    def close(self) -> None:
+        pass
+
+
+def make_client(grid: lanes_mod.Grid, mix: dict, seed: int):
+    """The client the mix's ``loop`` names."""
+    if mix.get("loop", "closed") == "service":
+        from chipbench.service import OpenService
+        return OpenService(grid, mix, seed)
+    return ClosedSweep(grid, mix)
 
 
 # ----------------------------------------------------------------------
@@ -327,6 +359,34 @@ def _read(cell: Cell, metrics: list, ctx: Window) -> dict:
     return out
 
 
+def trace_whole(ctx: Window) -> dict:
+    """Whether the trace holds the whole window: every chip ran the engine
+    as often as the window called it (a profile cut short has fewer
+    runs), with each chip's busy seconds inside the window."""
+    lo, hi = ctx.trace.window
+    if ctx.stats_open is not None:
+        calls = ctx.stats_close["n_slices"] - ctx.stats_open["n_slices"]
+    else:
+        calls = sum(r.telemetry.engine_calls for _, _, r in ctx.requests)
+    runs = [ctx.trace.runs.get(d, {}).get("jit_engine_fn", 0)
+            for d in range(ctx.n_devices)]
+    return dict(whole=all(r == calls for r in runs),
+                busy_s=[ctx.trace.busy_ns(d, lo, hi) / 1e9
+                        for d in range(ctx.n_devices)],
+                window_s=(hi - lo) / 1e9, engine_calls=calls,
+                engine_runs=runs)
+
+
+def read_traced(cell: Cell, ctx: Window) -> tuple[dict, dict]:
+    """The per-layer metrics of a traced window, and :func:`trace_whole`.
+    A trace that is not whole would give wrong device times, so no
+    metric read from it (``source`` ``device_trace``) is reported."""
+    seen = trace_whole(ctx)
+    metrics = [m for m in cell.per_layer
+               if seen["whole"] or m["source"] != "device_trace"]
+    return _read(cell, metrics, ctx), seen
+
+
 def run(workload: str, seed: int, seconds: float, trace: bool, *,
         t_start: float, root: str = ROOT, bench_dir: str = BENCH_DIR,
         require_chip: bool = True, trace_dir: str | None = None) -> dict:
@@ -341,50 +401,61 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     grid = lanes_mod.Grid(cell.config, seed, os.path.join(bench_dir, "kinds"))
     if trace:
         seconds = min(seconds, float(cell.mix["trace_seconds"]))
-    client = ClosedSweep(grid, cell.mix)
-    client.warm_up()
-    n0, s0 = clock.read()
-    if trace:
-        trace_dir = trace_dir or os.path.join(
-            root, "experiments", "chipbench", "trace", workload)
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        # the Python tracer would time every interpreter call and slow
-        # the host several times over: spans and device only
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    t_open = time.perf_counter()
-    setup_s = t_open - t_start
-    print("setup: " + json.dumps(dict(
-        setup_s=setup_s, compiles=n0, compile_s=s0, cache=cache)),
-        file=sys.stderr, flush=True)
-    with span("window"):
-        got = client.window(seconds, t_open)
-    if trace:
-        jax.profiler.stop_trace()
-    n1, s1 = clock.read()
-    print("window: " + json.dumps(dict(
-        window_s=got["t_close"] - t_open, compiles=n1 - n0,
-        compile_s=s1 - s0)), file=sys.stderr, flush=True)
-    peak = _memory_peak(devs)
+    client = make_client(grid, cell.mix, seed)
+    try:
+        client.warm_up()
+        n0, s0 = clock.read()
+        if trace:
+            trace_dir = trace_dir or os.path.join(
+                root, "experiments", "chipbench", "trace", workload)
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            # the Python tracer would time every interpreter call and slow
+            # the host several times over: spans and device only
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        t_open = time.perf_counter()
+        setup_s = t_open - t_start
+        print("setup: " + json.dumps(dict(
+            setup_s=setup_s, compiles=n0, compile_s=s0, cache=cache)),
+            file=sys.stderr, flush=True)
+        with span("window"):
+            got = client.window(seconds, t_open)
+        t_stop = time.perf_counter()
+        if trace:
+            jax.profiler.stop_trace()
+        stop_s = time.perf_counter() - t_stop
+        n1, s1 = clock.read()
+        print("window: " + json.dumps(dict(
+            window_s=got["t_close"] - t_open, compiles=n1 - n0,
+            compile_s=s1 - s0)), file=sys.stderr, flush=True)
+        peak = _memory_peak(devs)
+    finally:
+        client.close()
     ctx = Window(cell=cell, setup_s=setup_s, t_open=t_open,
                  t_close=got["t_close"], lanes=got["lanes"],
                  requests=got["requests"],
                  stepped_pe_ticks=got["stepped_pe_ticks"],
-                 n_devices=cell.chips)
+                 n_devices=cell.chips, stats_open=got.get("stats_open"),
+                 stats_close=got.get("stats_close"))
     dev = devs[0]
     device = dict(platform=dev.platform, kind=dev.device_kind,
                   count=len(devs), memory_peak_bytes=peak)
     out = {}
     if trace:
         from chipbench import trace as trace_mod
+        t_load = time.perf_counter()
         ctx.trace = trace_mod.load(trace_dir, cell.chips)
-        busy = [ctx.trace.busy_ns(d, ctx.trace.window[0],
-                                  ctx.trace.window[1])
-                for d in range(cell.chips)]
-        device["busy_s"] = float(np.mean(busy)) / 1e9
-        device["window_s"] = (ctx.trace.window[1] - ctx.trace.window[0]) / 1e9
-        metrics = _read(cell, cell.per_layer, ctx)
+        load_s = time.perf_counter() - t_load
+        metrics, seen = read_traced(cell, ctx)
+        device["busy_s"] = float(np.mean(seen["busy_s"]))
+        device["window_s"] = seen["window_s"]
+        print("trace: " + json.dumps(dict(seen, stop_s=stop_s,
+                                          load_s=load_s)),
+              file=sys.stderr, flush=True)
+        if not seen["whole"]:
+            print("trace: not whole, the device_trace metrics are left "
+                  "out", file=sys.stderr, flush=True)
         out["breakdown"] = ctx.trace.breakdown()
     else:
         metrics = _read(cell, cell.end_to_end, ctx)

@@ -3,6 +3,8 @@ picks what it reads from the window (``ctx``, a
 :class:`chipbench.harness.Window`) and reduces it here."""
 from __future__ import annotations
 
+import math
+
 
 def pe_cycles(lanes) -> int:
     """Simulated PE-cycles of the lanes that came back: each lane's cycles
@@ -51,3 +53,30 @@ def device_idle_share(ctx) -> float | None:
         return None
     return sum(1.0 - tr.busy_ns(d, lo, hi) / (hi - lo)
                for d in range(ctx.n_devices)) / ctx.n_devices
+
+
+def stats_change(ctx, key: str) -> float | None:
+    """The change of ``SweepService.stats[key]`` over the window; None
+    where no service ran it."""
+    if ctx.stats_open is None or ctx.stats_close is None:
+        return None
+    return ctx.stats_close[key] - ctx.stats_open[key]
+
+
+def latency_s(lane) -> float:
+    """Seconds from a lane's due time to its result; a lane that failed
+    or never came misses any limit."""
+    if lane.result is None or lane.error is not None or lane.t_done is None:
+        return math.inf
+    return lane.t_done - lane.t_due
+
+
+def lane_percentile_s(ctx, q: float) -> float | None:
+    """The ``q`` quantile, by nearest rank, of :func:`latency_s` over the
+    lanes due in the window; None where it lies among lanes that never
+    came."""
+    lat = sorted(latency_s(ln) for ln in ctx.lanes if ln.t_due is not None)
+    if not lat:
+        return None
+    v = lat[math.ceil(q * len(lat)) - 1]
+    return v if math.isfinite(v) else None

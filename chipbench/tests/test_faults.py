@@ -3,8 +3,10 @@
 Each test drives a whole run of a tiny cell on the CPU (the look for a
 chip skipped) with the engine broken underneath the window, the way a
 wrong optimisation would break it, and sees ``correct`` come out false.
-The sound run beside them comes out true.  The cells have no exchange
-between chips to leave out: every cell runs on one chip.
+The sound run beside them comes out true.  The blocking client and the
+open-loop service client are each broken in their own window; the
+four-chip cell's fault, the lanes of three chips never coming back, is
+in ``test_sharded.py``.
 
 The witnesses of the statistics run after the window, on the engine as
 the program has it; a fault planted in the window alone is one the chip
@@ -17,7 +19,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench import harness
+from chipbench import harness, service
+from chipbench.tests.conftest import make_bench
+from chipbench.tests.test_service import SERVICE_MIX
 
 
 def _copy(st):
@@ -79,6 +83,39 @@ def _break_window(monkeypatch, fault):
     monkeypatch.setattr(harness.ClosedSweep, "window", closed_window)
 
 
+def _break_service_window(monkeypatch, fault):
+    """Break the resident service's engine for the window alone: the
+    service fetched its engine once, at set-up.  A lane that never comes
+    is given up sooner than on the chip."""
+    real = service.OpenService.window
+
+    def window(self, *a):
+        engine = self.svc._engine
+        self.svc._engine = _broken(engine, fault)
+        try:
+            return real(self, *a)
+        finally:
+            self.svc._engine = engine
+
+    monkeypatch.setattr(service.OpenService, "window", window)
+    monkeypatch.setattr(service.OpenService, "DRAIN_S", 5.0)
+
+
+@pytest.fixture
+def service_bench(tmp_path):
+    # lanes due faster than a slice retires them, so every super-lane of
+    # the service holds one
+    return make_bench(tmp_path, mixes={"service": dict(SERVICE_MIX,
+                                                       rate=30.0)})
+
+
+def _run_service(service_bench):
+    root, bench = service_bench
+    return harness.run("tiny.service", 2 ** 31 + 98, 1.0, False,
+                       t_start=time.perf_counter(), root=root,
+                       bench_dir=bench, require_chip=False)
+
+
 def _run(tiny_bench):
     root, bench = tiny_bench
     return harness.run("tiny.closed", 2 ** 31 + 99, 0.01, False,
@@ -121,3 +158,22 @@ def test_timing_model_change_is_caught_by_the_golden(tiny_bench,
     checks = {k: c["value"] for k, c in res["checks"].items()}
     assert checks["wrong_answers"] == 0 and checks["stat_drift"] == 0
     assert checks["golden_drift"] > 0 and not res["correct"]
+
+
+@pytest.mark.parametrize("fault", [_unchanged, _half_batch, _altered])
+def test_broken_service_engine_is_not_correct(service_bench, monkeypatch,
+                                              fault):
+    _break_service_window(monkeypatch, fault)
+    res = _run_service(service_bench)
+    assert not res["correct"], res["checks"]
+    assert res["failed"] > 0
+
+
+def test_service_statistics_drift_is_caught_by_the_cpu_witness(
+        service_bench, monkeypatch):
+    """Answers right, statistics off in the service's window alone."""
+    _break_service_window(monkeypatch, _hops)
+    res = _run_service(service_bench)
+    checks = {k: c["value"] for k, c in res["checks"].items()}
+    assert checks["wrong_answers"] == 0 and checks["stat_drift"] > 0
+    assert not res["correct"]

@@ -101,3 +101,20 @@ def test_per_request_spans_and_host_time():
 def test_a_trace_without_a_window_is_refused():
     with pytest.raises(ValueError):
         T.from_planes([], chips=1)
+
+
+def test_program_runs_are_counted_per_chip_inside_the_window():
+    planes = _planes({"planes": [
+        {"name": T.HOST_PLANE, "lines": [{"name": "python", "events": [
+            ["chipbench.window", 100, 900]]}]},
+        {"name": "/device:TPU:0", "lines": [{"name": T.MODULES_LINE,
+                                             "events": [
+            ["jit_engine_fn(12)", 50, 40], ["jit_engine_fn(12)", 150, 300],
+            ["jit__install_fn(7)", 500, 10], ["jit_engine_fn(12)", 600, 200],
+            ["jit_engine_fn(12)", 1000, 50]]}]},
+        {"name": "/device:TPU:1", "lines": [{"name": T.MODULES_LINE,
+                                             "events": [
+            ["jit_engine_fn(12)", 150, 300]]}]}]})
+    tr = T.from_planes(planes, chips=2)
+    assert tr.runs[0] == {"jit_engine_fn": 2, "jit__install_fn": 1}
+    assert tr.runs[1] == {"jit_engine_fn": 1}

@@ -8,8 +8,10 @@ sweep):
   event per operation the TensorCore ran, named by the HLO instruction
   (``%fusion.12 = s32[...] fusion(...)``); a ``while`` or ``conditional``
   event encloses the events of its body.  ``XLA Modules`` has one event
-  per program run, ``Async XLA Ops`` the copy-start/copy-done windows of
-  DMAs that overlap compute.  Busy time is read from ``XLA Ops`` alone.
+  per program run (``jit_engine_fn(<fingerprint>)``), ``Async XLA Ops``
+  the copy-start/copy-done windows of DMAs that overlap compute.  Busy
+  time is read from ``XLA Ops`` alone; the program runs are counted, to
+  show that the trace holds every engine call the window made.
 * the host plane ``/host:CPU``: one line per host thread (``python`` is
   the interpreter's), holding the runtime's own events and the
   benchmark's spans (``TraceAnnotation`` events named ``chipbench.<span>``).
@@ -26,6 +28,7 @@ import os
 
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "chipbench."
 TOP = 10
@@ -98,11 +101,12 @@ class Trace:
     benchmark's spans; and the window (the ``chipbench.window`` span)."""
 
     def __init__(self, busy: dict, op_s: dict, spans: list,
-                 window: tuple[int, int]):
+                 window: tuple[int, int], runs: dict | None = None):
         self.busy = busy              # {chip: merged [(start, end)]}
         self.op_s = op_s              # {chip: Counter(op -> seconds)}
         self.spans = spans            # [(name, start, end)], prefix removed
         self.window = window
+        self.runs = runs or {}        # {chip: Counter(program -> runs)}
         self.any_busy = merge(iv for v in busy.values() for iv in v)
 
     def busy_ns(self, chip: int, lo: int, hi: int) -> int:
@@ -170,7 +174,7 @@ def from_planes(planes, chips: int) -> Trace:
     if not windows:
         raise ValueError("the trace holds no chipbench.window span")
     lo, hi = windows[-1]
-    busy, op_s = {}, {}
+    busy, op_s, runs = {}, {}, {}
     for plane in planes:
         if not plane.name.startswith(DEVICE_PLANE):
             continue
@@ -181,15 +185,20 @@ def from_planes(planes, chips: int) -> Trace:
         if chip >= chips:
             continue
         events = []
+        runs[chip] = collections.Counter()
         for line in plane.lines:
             if line.name == OPS_LINE:
                 for e in line.events:
                     s = int(e.start_ns)
                     events.append((s, s + int(e.duration_ns),
                                    op_name(e.name)))
+            elif line.name == MODULES_LINE:
+                runs[chip].update(e.name.split("(", 1)[0]
+                                  for e in line.events
+                                  if lo <= int(e.start_ns) < hi)
         busy[chip] = merge((s, e) for s, e, _ in events)
         op_s[chip] = self_times(events, lo, hi)
-    return Trace(busy, op_s, spans, (lo, hi))
+    return Trace(busy, op_s, spans, (lo, hi), runs)
 
 
 def load(trace_dir: str, chips: int) -> Trace:
