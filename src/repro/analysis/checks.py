@@ -188,11 +188,15 @@ def check_capacity(wl: Any, summary: ChainSummary | None = None,
     The engine's overflow guard fires at ``pend_n >= PEND_CAP - 2``; the
     comment-prose proof in ``machine.py`` shows no unit can push past it
     *provided* ``STREAM_THROTTLE <= PEND_CAP - 3`` (decode reserves one
-    slot, compute two, the stream gate bounds post-execution pushes).
-    This check re-derives that inequality against the live module
+    slot, compute two, the stream gate bounds post-execution pushes) and
+    ``NET_RESERVE >= DEPTH + 5`` (a message from the network needs that
+    many free slots, so the PE's own messages can always retire).
+    This check re-derives those inequalities against the live module
     constants and bounds the per-PE stream wait queue, whose guarantee
     (``swq_n < stream_wait_cap - 1`` accept gate) is the one capacity
-    limit the discipline does NOT cover.
+    limit the discipline does NOT cover: network messages that find the
+    pending FIFO short of room park in the same queue, so their count is
+    bounded at run time only.
     """
     from repro.core import machine  # late import: constants monkeypatchable
 
@@ -204,6 +208,12 @@ def check_capacity(wl: Any, summary: ChainSummary | None = None,
             f"{machine.PEND_CAP - 3}: the stream unit can push past the "
             "decode/compute reservations and overrun the pending FIFO "
             "(provable overflow; see the discipline proof in machine.py)"))
+    if machine.NET_RESERVE < machine.DEPTH + 5:
+        out.append(Finding(
+            "capacity.reservation-discipline", "error",
+            f"NET_RESERVE={machine.NET_RESERVE} < DEPTH+5="
+            f"{machine.DEPTH + 5}: network messages can push the pending "
+            "FIFO past the overflow guard (see NET_RESERVE in machine.py)"))
     if summary is None:
         summary = lift(wl)
     if stream_wait_cap is None:
@@ -232,7 +242,8 @@ def check_capacity(wl: Any, summary: ChainSummary | None = None,
             "capacity.pend-pressure", "info",
             f"PE {hot} generates {int(press[hot])} pending-FIFO pushes "
             f"(> PEND_CAP-2 = {machine.PEND_CAP - 2} slots); safe only "
-            "through the reservation discipline's backpressure, not a "
+            "through the reservation discipline (network messages park "
+            "in the wait queue while the FIFO is short of room), not a "
             "static in-flight bound", pe=hot))
     return out
 

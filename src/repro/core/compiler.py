@@ -29,12 +29,13 @@ from repro.core.am import (
     make_static_am,
 )
 from repro.core.machine import MachineConfig
+from repro.core.spans import span
 
 __all__ = [
-    "CompiledWorkload", "csr_from_dense", "random_sparse",
-    "build_spmv", "build_spmspm", "build_spmadd", "build_sddmm",
-    "build_matmul", "build_mv", "build_conv", "build_bfs", "build_sssp",
-    "build_pagerank",
+    "CompiledWorkload", "TiledWorkload", "csr_from_dense", "random_sparse",
+    "plan_spmv_tiles", "build_spmv", "build_spmspm", "build_spmadd",
+    "build_sddmm", "build_matmul", "build_mv", "build_conv", "build_bfs",
+    "build_sssp", "build_pagerank",
 ]
 
 
@@ -128,6 +129,10 @@ class _Builder:
     def push_am(self, pe: int, m: np.ndarray) -> None:
         self.ams[pe].append(m)
 
+    def push_ams(self, pe: int, ms: np.ndarray) -> None:
+        """Queue the rows of ``ms`` at ``pe``, in order."""
+        self.ams[pe].extend(ms)
+
     def finish(self, prog_rows, read_result, expected, name):
         n = self.cfg.n_pes
         qcap = max(1, max(len(q) for q in self.ams))
@@ -139,8 +144,8 @@ class _Builder:
         alen = np.zeros((n,), dtype=np.int32)
         total = 0
         for p in range(n):
-            for k, msg in enumerate(self.ams[p]):
-                sams[p, k] = msg
+            if self.ams[p]:
+                sams[p, :len(self.ams[p])] = np.stack(self.ams[p])
             alen[p] = len(self.ams[p])
             total += len(self.ams[p])
         prog = np.zeros((max(len(prog_rows), 1), am.CFG_F), dtype=np.int32)
@@ -160,22 +165,126 @@ def _place_rows(rowptr, col, n_pes, strategy, n_cols):
         n_cols=n_cols)
 
 
+@dataclasses.dataclass
+class TiledWorkload:
+    """A workload larger than the fabric, cut into tiles (paper §3.1.4).
+
+    Each tile is an ordinary :class:`CompiledWorkload` built on a column
+    block of the operand.  The fabric runs the tiles one after another
+    with a global synchronisation between them, so a sweep runs each tile
+    as a lane and folds them back into one result
+    (:func:`repro.core.sweep.sweep`).  ``read_result`` takes the tiles'
+    final memory images stacked on a leading axis, ``(T, N, MEM)``, and
+    sums their partial answers.
+    """
+
+    tiles: tuple                      # tuple[CompiledWorkload, ...]
+    blocks: tuple                     # ((lo, hi), ...) column block per tile
+    expected: np.ndarray              # numpy oracle of the whole workload
+    mem_budget: int                   # per-PE words each tile was fit into
+    name: str = ""
+
+    @property
+    def n_static_ams(self) -> int:
+        return sum(t.n_static_ams for t in self.tiles)
+
+    def read_result(self, mem_val: np.ndarray) -> np.ndarray:
+        return sum(t.read_result(mem_val[i])
+                   for i, t in enumerate(self.tiles))
+
+    def check(self, mem_val: np.ndarray) -> bool:
+        return bool(np.array_equal(self.read_result(mem_val), self.expected))
+
+
+def _pe_counts(pe_of: np.ndarray, n_pes: int, weights=None) -> np.ndarray:
+    return np.bincount(pe_of, weights=weights, minlength=n_pes)
+
+
+def plan_spmv_tiles(rowptr: np.ndarray, col: np.ndarray,
+                    row_to_pe: np.ndarray, n_cols: int,
+                    cfg: MachineConfig) -> list[tuple[int, int]]:
+    """Cut an SpMV operand into column blocks that each fit one PE array.
+
+    A block ``[lo, hi)`` fits when on every PE its x words (the block's
+    slice of x, spread over all PEs), the y words of the rows placed there
+    and one word per static AM (one AM per nonzero, its value held in
+    SRAM) sum to at most ``cfg.mem_words``, and the AMs to at most
+    ``cfg.queue_cap``.  Blocks are grown greedily from per-column, per-PE
+    nonzero counts, so a row is split across as many blocks as it needs.
+    Raises :class:`MemoryError` where even a single column does not fit.
+    """
+    n_pes = cfg.n_pes
+    row_of = np.repeat(np.arange(rowptr.shape[0] - 1), np.diff(rowptr))
+    # cum[j, p]: nonzeros of columns < j whose row lives on PE p
+    per_col = np.zeros((n_cols + 1, n_pes), np.int64)
+    np.add.at(per_col, (col + 1, row_to_pe[row_of]), 1)
+    cum = per_col.cumsum(axis=0)
+    y_words = _pe_counts(row_to_pe, n_pes)
+    # x_words[w, p]: words of a w-wide x block on PE p (uniform partition)
+    w = np.arange(n_cols + 1)[:, None]
+    p = np.arange(n_pes)[None, :]
+    x_words = (-(p * w) // n_pes) - (-((p + 1) * w) // n_pes)
+    blocks, lo = [], 0
+    while lo < n_cols:
+        ams = cum[lo + 1:] - cum[lo]                 # blocks [lo, lo+1..n)
+        words = ams + x_words[1:n_cols - lo + 1] + y_words
+        fits = ((words <= cfg.mem_words) & (ams <= cfg.queue_cap)).all(1)
+        width = int(np.argmin(fits)) if not fits.all() else fits.shape[0]
+        if width == 0:
+            raise MemoryError(
+                f"column {lo} alone needs {int(words[0].max())} words on "
+                f"one PE > {cfg.mem_words}: no column tiling fits it")
+        blocks.append((lo, lo + width))
+        lo += width
+    return blocks
+
+
+def _spmv_fits_whole(rowptr, row_to_pe, n_cols: int,
+                     cfg: MachineConfig) -> bool:
+    """Whether :func:`build_spmv` lays the operand out whole: x and y in
+    each PE's SRAM, and its nonzeros' AMs in the AM queue."""
+    n_pes = cfg.n_pes
+    words = (_pe_counts(partition.uniform_partition(n_cols, n_pes), n_pes)
+             + _pe_counts(row_to_pe, n_pes))
+    ams = _pe_counts(row_to_pe, n_pes, weights=np.diff(rowptr))
+    return bool((words <= cfg.mem_words).all()
+                and (ams <= cfg.queue_cap).all())
+
+
 # ============================================================================
 # SpMV  (Fig. 4/5):  y = A @ x
 #   static AM per nonzero A[i,j]:
 #     [LOAD2 x[j] @ PE(x_j)] -> [MUL en-route] -> [STORE_ADD y[i] @ PE(y_i)]
 # ============================================================================
 def build_spmv(a_dense: np.ndarray, x: np.ndarray, cfg: MachineConfig,
-               *, strategy: str = "dissimilarity") -> CompiledWorkload:
+               *, strategy: str = "dissimilarity",
+               row_to_pe: np.ndarray | None = None
+               ) -> CompiledWorkload | TiledWorkload:
+    """SpMV on one PE array.  An operand too large to lay out whole comes
+    back as a :class:`TiledWorkload` of column blocks
+    (:func:`plan_spmv_tiles`), every tile keeping the rows where the
+    whole operand's placement puts them.  ``row_to_pe`` fixes that
+    placement instead of ``strategy``."""
     m, n = a_dense.shape
     rowptr, col, val = csr_from_dense(a_dense)
-    b = _Builder(cfg)
     n_pes = cfg.n_pes
+    if row_to_pe is None:
+        row_to_pe = _place_rows(rowptr, col, n_pes, strategy, n).row_to_pe
+        if not _spmv_fits_whole(rowptr, row_to_pe, n, cfg):
+            with span("compile.tile_plan"):
+                blocks = plan_spmv_tiles(rowptr, col, row_to_pe, n, cfg)
+            tiles = tuple(build_spmv(a_dense[:, lo:hi], x[lo:hi], cfg,
+                                     row_to_pe=row_to_pe)
+                          for lo, hi in blocks)
+            expected = a_dense.astype(np.int64) @ x.astype(np.int64)
+            return TiledWorkload(tiles=tiles, blocks=tuple(blocks),
+                                 expected=expected,
+                                 mem_budget=cfg.mem_words, name="spmv")
+    b = _Builder(cfg)
 
-    place = _place_rows(rowptr, col, n_pes, strategy, n)
     x_pe = partition.uniform_partition(n, n_pes)
     # y[i] is co-located ("aligned") with A row i  (§3.1.1)
-    y_pe = place.row_to_pe
+    y_pe = np.asarray(row_to_pe)
 
     x_addr = np.array([b.alloc(int(x_pe[j]), 1) for j in range(n)])
     for j in range(n):
@@ -187,13 +296,19 @@ def build_spmv(a_dense: np.ndarray, x: np.ndarray, cfg: MachineConfig,
         cfg_entry(OP_STORE_ADD, 2),            # after MUL
         cfg_entry(OP_NOP),                     # terminal
     ]
-    for i in range(m):
-        for e in range(int(rowptr[i]), int(rowptr[i + 1])):
-            j = int(col[e])
-            b.push_am(int(place.row_to_pe[i]), make_static_am(
-                dst=(int(x_pe[j]), int(y_pe[i]), -1), pc=0, opcode=OP_LOAD2,
-                res=int(y_addr[i]), op1=int(val[e]), op2=int(x_addr[j]),
-                tag=i))
+    # one AM per nonzero, queued at its row's PE in row-major order
+    row_of = np.repeat(np.arange(m), np.diff(rowptr))
+    ams = np.zeros((row_of.shape[0], am.MSG_F), np.int32)
+    ams[:] = make_static_am(dst=(0, 0, -1), pc=0, opcode=OP_LOAD2, res=0,
+                            op1=0, op2=0)
+    ams[:, am.F_DST0] = x_pe[col]
+    ams[:, am.F_DST1] = y_pe[row_of]
+    ams[:, am.F_RES] = y_addr[row_of]
+    ams[:, am.F_OP1] = val
+    ams[:, am.F_OP2] = x_addr[col]
+    ams[:, am.F_TAG] = row_of
+    for pe in range(n_pes):
+        b.push_ams(pe, ams[y_pe[row_of] == pe])
 
     expected = (a_dense.astype(np.int64) @ x.astype(np.int64)).astype(np.int64)
 

@@ -143,6 +143,20 @@ STREAM_THROTTLE = 8   # stream unit pauses while pending queue is this deep
 # top of the guard's high-water margin.  Checked here once because the
 # constants are module-level (tests monkeypatch them to force violations).
 assert STREAM_THROTTLE <= PEND_CAP - 3, "stream throttle must sit below cap"
+# Free pending slots a message arriving from the network needs before an
+# execution unit takes it, when executing it would push into the FIFO.
+# Short of them the decode unit parks it in the wait queue, as it does a
+# STREAM task, and it re-enters through the FIFO once that has as many
+# free.  The PE's own messages in its inject port (TIA's anchored ALU ops,
+# local static AMs) keep the 1/2-slot gates.  A hub PE needs both halves:
+# one that keeps executing requests fills its FIFO with anchored ops that
+# can then never execute (a deadlock inside one PE), and one that merely
+# refuses them leaves them in the network, where they block its own
+# replies (a deadlock through the network).  DEPTH + 5: with network and
+# parked messages held back above PEND_CAP - NET_RESERVE, occupancy plus
+# the inject port's DEPTH slots grows only by the last cycle's two pushes,
+# so it stays below the guard's PEND_CAP - 2.
+NET_RESERVE = DEPTH + 5
 
 # --- fabric execution modes (per-lane runtime data) -------------------------
 # Bitmask encoding of the three mode behaviours.  The mode travels with the
@@ -613,7 +627,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             # stream unit is busy, which together with the deep pending FIFO
             # gives the forward-progress guarantee the paper gets from bubble
             # flow control + placement/timeouts (§3.4).
-            pend_free = PEND_CAP - st.pend_n               # (N,)
+            pend_free = (PEND_CAP - st.pend_n)[:, None, None]  # (N,1,1)
             slot_v = jnp.arange(DEPTH)[None, None, :] < st.buf_n[:, :, None]
             all_m = st.buf                                  # (N,5,D,F)
             opn_a = all_m[..., F_OP]                        # (N,5,D)
@@ -636,9 +650,20 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             # network regardless of pending back-pressure).
             no_emit_a = (opn_a == OP_STORE_ADD) | (opn_a == OP_STORE_SET) | \
                 (stream_a & swq_ok[:, None, None])
-            mem_cand = local_a & is_mem_op(opn_a) & \
-                ((pend_free >= 1)[:, None, None] | no_emit_a) & \
-                (~stream_a | swq_ok[:, None, None])          # (N,5,D)
+            # A message from the network executes with NET_RESERVE free
+            # slots, the PE's own (inject port) with the reservations below.
+            # Short of them, a network message that would push parks in the
+            # wait queue as a STREAM task does (the decode unit takes it
+            # there), and re-enters through the pending FIFO once it has
+            # room: the PE keeps consuming what the network brings it.
+            net_a = (jnp.arange(PORTS) != P_INJ)[None, :, None]
+            net_room = pend_free >= NET_RESERVE
+            mem_room = jnp.where(net_a, net_room, pend_free >= 1)
+            park_a = local_a & net_a & ~net_room & swq_ok[:, None, None] & (
+                is_alu_op(opn_a) | (is_mem_op(opn_a) & ~no_emit_a))
+            mem_cand = (local_a & is_mem_op(opn_a) &
+                        (mem_room | no_emit_a) &
+                        (~stream_a | swq_ok[:, None, None])) | park_a
             # Pending-FIFO reservation discipline (the consumption guarantee,
             # §3.4).  Three producers may push in one cycle — decode output,
             # compute output, stream spawn — and each is gated so occupancy
@@ -646,6 +671,11 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             #   * decode emits only with >= 1 free slot;
             #   * compute emits only with >= 2 free slots (its own push PLUS a
             #     same-cycle decode push: after both, pend_n <= PEND_CAP);
+            #   * either unit takes a message that arrived from the network
+            #     only with >= NET_RESERVE free slots, so the PE's own
+            #     messages in its inject port can always retire and the FIFO
+            #     drains (see NET_RESERVE); short of them it parks, and a
+            #     parked message re-enters the FIFO only with as many;
             #   * the stream gate checks the *post-execution-push* count
             #     against STREAM_THROTTLE (<= PEND_CAP - 3, asserted at module
             #     scope), far below the cap.
@@ -654,7 +684,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             # gate an execution unit — i.e. consumption would no longer be
             # unconditional (tests/test_pend_guard.py holds the invariant).
             alu_cand = local_a & is_alu_op(opn_a) & \
-                (pend_free >= 2)[:, None, None]
+                jnp.where(net_a, net_room, pend_free >= 2)
 
             def sel_dual():
                 # separate decode + compute units (Fig. 8b): one of each may
@@ -670,9 +700,11 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
                 sel_one = _pick_one((mem_cand | alu_cand)
                                     .reshape(n, PORTS * DEPTH),
                                     st.rr).reshape(n, PORTS, DEPTH)
-                return sel_one & is_mem_op(opn_a), sel_one & is_alu_op(opn_a)
+                return (sel_one & (is_mem_op(opn_a) | park_a),
+                        sel_one & is_alu_op(opn_a) & ~park_a)
 
             sel_mem3, sel_alu3 = pick_mode(dual_on, sel_dual, sel_single)
+            parks = (sel_mem3 & park_a).any(axis=(1, 2))   # decode parks
             any_alu_local = sel_alu3.any(axis=(1, 2))
             opn = heads[:, :, F_OP]
 
@@ -717,7 +749,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
 
         # ============== EXECUTE: DECODE UNIT (memory-class) ================
         with jax.named_scope("cycle.decode"):
-            op = jnp.where(mv, msg[:, F_OP], OP_NOP)
+            op = jnp.where(mv & ~parks, msg[:, F_OP], OP_NOP)
             pc = msg[:, F_PC]
             cfg_row = prog_j[jnp.clip(pc, 0, prog_j.shape[0] - 1)]  # (N,CFG_F)
             addr_res = jnp.clip(msg[:, F_RES], 0, cfg.mem_words - 1)
@@ -776,7 +808,7 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             cond_no = ((op == OP_STORE_MIN) & ~improved) | \
                       ((op == OP_CHECKSET) & ~was_unset)
             starts_stream = mv & (op == OP_STREAM)
-            emits = mv & ~terminal & ~cond_no & ~starts_stream & \
+            emits = mv & ~parks & ~terminal & ~cond_no & ~starts_stream & \
                 (cfg_row[:, C_OP] != OP_NOP)
             nxt = nxt.at[:, F_VALID].set(jnp.where(emits, 1, 0))
 
@@ -812,8 +844,8 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             wpos = (swq_h + swq_n) % cfg.stream_wait_cap
             swq = jax.vmap(
                 lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
-            )(swq, wpos, msg, starts_stream)
-            swq_n = swq_n + starts_stream.astype(jnp.int32)
+            )(swq, wpos, msg, starts_stream | parks)
+            swq_n = swq_n + (starts_stream | parks).astype(jnp.int32)
 
             # -- STREAM issue: an idle decode unit pops the next waiting task.
             # Descriptor word (mem_val=base, meta0=count) at Op2 (address) — or
@@ -823,6 +855,10 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
                 issue = issue & act
             task = jnp.take_along_axis(
                 swq, swq_h[:, None, None].repeat(MSG_F, 2), 1)[:, 0, :]
+            # a parked message at the head is no stream task: it leaves
+            # through the pending FIFO below, once that has room
+            parked = issue & (task[:, F_OP] != OP_STREAM)
+            issue = issue & ~parked
             t_res = jnp.clip(task[:, F_RES], 0, cfg.mem_words - 1)
             t_op2 = jnp.clip(task[:, F_OP2], 0, cfg.mem_words - 1)
             desc_a = jnp.where(task[:, F_OP2C] == 1, t_res, t_op2)
@@ -858,6 +894,9 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             can_emit = stream_on & (pend_n < STREAM_THROTTLE)
             if act is not None:
                 can_emit = can_emit & act
+            unpark = parked & (pend_n <= PEND_CAP - NET_RESERVE)
+            swq_h = (swq_h + unpark.astype(jnp.int32)) % cfg.stream_wait_cap
+            swq_n = swq_n - unpark.astype(jnp.int32)
             e_addr = jnp.clip(stream_base, 0, cfg.mem_words - 1)
             e_val = jnp.take_along_axis(mem_val, e_addr[:, None], 1)[:, 0]
             e_meta = jnp.take_along_axis(
@@ -896,11 +935,14 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
                 jnp.where(use_meta_dst, t[:, F_DST2], rot_t[:, F_DST2]))
             sp = sp.at[:, F_VIA].set(-1)
             sp = maybe_anchor(sp)
+            # the spawn's slot, or an unparked message's (never both: a
+            # parked head means the stream unit is idle)
+            push2 = can_emit | unpark
             pos2 = (pend_h + pend_n) % PEND_CAP
             pend = jax.vmap(
                 lambda q, i, v, m: q.at[i].set(jnp.where(m, v, q[i]))
-            )(pend, pos2, sp, can_emit)
-            pend_n = pend_n + can_emit.astype(jnp.int32)
+            )(pend, pos2, jnp.where(unpark[:, None], task, sp), push2)
+            pend_n = pend_n + push2.astype(jnp.int32)
             stream_base = jnp.where(can_emit, stream_base + 1, stream_base)
             stream_left = jnp.where(can_emit, stream_left - 1, stream_left)
             stream_on = stream_on & (stream_left > 0)
@@ -1026,7 +1068,8 @@ def _make_cycle(cfg: MachineConfig, n_pes: int | None = None):
             # endpoints always belong to the same sub-mesh).
             busy = mv | mv_alu | can_emit
             st_busy = st.st_busy + busy.astype(jnp.int32)
-            st_exec = st.st_exec + mv.astype(jnp.int32) + mv_alu.astype(jnp.int32)
+            st_exec = st.st_exec + (mv & ~parks).astype(jnp.int32) \
+                + mv_alu.astype(jnp.int32)
             st_enroute = st.st_enroute + sel_icept.any(axis=1).astype(jnp.int32)
             st_stall = st.st_stall + (stall_net | stall_local).astype(jnp.int32)
             st_hops = st.st_hops + grants.sum(axis=1).astype(jnp.int32)
@@ -1192,7 +1235,7 @@ def _engine_key(cfg: MachineConfig, n_max: int, chunk: int,
     the partitioning of the executable — keys separately.
     """
     return (_engine_key_cfg(cfg), int(n_max), chunk, int(n_devices),
-            PEND_CAP, STREAM_THROTTLE)
+            PEND_CAP, STREAM_THROTTLE, NET_RESERVE)
 
 
 def clear_engine_cache() -> None:

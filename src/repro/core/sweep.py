@@ -27,7 +27,10 @@ from __future__ import annotations
 
 import dataclasses
 
+import numpy as np
+
 from repro.core import machine
+from repro.core.compiler import TiledWorkload
 from repro.core.machine import MachineConfig, RunResult
 from repro.core.spans import span
 
@@ -41,7 +44,10 @@ class SweepRequest:
 
     * ``workloads`` — compiled workloads (anything with ``prog`` /
       ``static_ams`` / ``amq_len`` / ``mem_val`` / ``mem_meta``), or an
-      already-stacked :class:`repro.core.batch.BatchedWorkloads`.
+      already-stacked :class:`repro.core.batch.BatchedWorkloads`.  A
+      :class:`repro.core.compiler.TiledWorkload` runs its tiles as lanes
+      and comes back as one result (:func:`fold_tiles`); the per-lane
+      fields below name it once.
     * ``modes`` / ``geoms`` / ``cycle_hints`` — optional per-lane mode
       names or bitmasks, ``(width, height)`` meshes, and measured-cycle
       runtime hints.
@@ -208,6 +214,23 @@ class EngineTelemetry:
 
 
 @dataclasses.dataclass(frozen=True)
+class TileStats:
+    """The tiled lanes of a sweep (:class:`repro.core.compiler.TiledWorkload`).
+
+    ``am_fill`` is the tiles' static AMs over tiles x PEs x the per-PE
+    word budget each tile was fit into: the share of the tiles' SRAM
+    that holds nonzeros.
+    """
+    n_tiled_lanes: int
+    n_tiles: int
+    am_fill: float
+
+    def to_json(self) -> dict:
+        return dict(n_tiled_lanes=int(self.n_tiled_lanes),
+                    n_tiles=int(self.n_tiles), am_fill=float(self.am_fill))
+
+
+@dataclasses.dataclass(frozen=True)
 class SweepReport:
     """Everything a sweep produced: per-lane results + the schedules.
 
@@ -215,12 +238,14 @@ class SweepReport:
     hit ``lanes``), so migrating a call site is usually just swapping
     the call.  ``pack`` / ``shard`` are None when the corresponding
     switch was off.  ``telemetry`` carries the engine's dead-step
-    accounting (always present on the ``sweep()`` path).
+    accounting (always present on the ``sweep()`` path); ``tiles`` is
+    None unless the request held tiled lanes.
     """
     lanes: tuple                      # tuple[RunResult, ...] in input order
     pack: PackStats | None = None
     shard: ShardStats | None = None
     telemetry: EngineTelemetry | None = None
+    tiles: TileStats | None = None
 
     def __post_init__(self):
         object.__setattr__(self, "lanes", tuple(self.lanes))
@@ -249,7 +274,58 @@ class SweepReport:
             shard=None if self.shard is None else self.shard.to_json(),
             telemetry=(None if self.telemetry is None
                        else self.telemetry.to_json()),
+            tiles=None if self.tiles is None else self.tiles.to_json(),
         )
+
+
+def _expand_tiles(request: SweepRequest) -> tuple[list, dict, list]:
+    """Each tiled lane of ``request`` as one lane per tile.  Returns the
+    flat workloads, the per-lane request fields repeated per tile (a
+    lane's deadline bounds each of its tiles; its cycle hint is shared
+    out among them), and the number of flat lanes of each requested
+    lane."""
+    counts = [len(w.tiles) if isinstance(w, TiledWorkload) else 1
+              for w in request.workloads]
+    flat = [t for w in request.workloads
+            for t in (w.tiles if isinstance(w, TiledWorkload) else (w,))]
+
+    def repeat(v, share=False):
+        if v is None:
+            return None
+        return [x / c if share else x
+                for x, c in zip(v, counts) for _ in range(c)]
+
+    fields = dict(modes=repeat(request.modes), geoms=repeat(request.geoms),
+                  cycle_hints=repeat(request.cycle_hints, share=True),
+                  deadlines=repeat(request.deadlines))
+    return flat, fields, counts
+
+
+def fold_tiles(results) -> RunResult:
+    """One lane's result from its tiles' results, run one after another
+    with a global synchronisation between them (§3.1.4): cycles and
+    every statistic are the sums over the tiles, the lane completed if
+    every tile did, and ``mem_val`` stacks the tiles' final images
+    (``(T, N, MEM)``, zero-padded to the widest)."""
+    n = results[0].per_pe_busy.shape[0]
+    cycles = sum(r.cycles for r in results)
+    executed = sum(r.executed for r in results)
+    enroute = sum(r.enroute for r in results)
+    busy = sum(np.asarray(r.per_pe_busy) for r in results)
+    words = max(r.mem_val.shape[-1] for r in results)
+    mem = np.zeros((len(results), n, words), results[0].mem_val.dtype)
+    for i, r in enumerate(results):
+        mem[i, :, :r.mem_val.shape[-1]] = r.mem_val
+    return RunResult(
+        cycles=cycles, mem_val=mem,
+        utilization=executed / max(1, cycles * n),
+        busy_frac=float(busy.sum()) / max(1, cycles * n),
+        per_pe_busy=busy, executed=executed, enroute=enroute,
+        enroute_frac=enroute / max(1, executed),
+        hops=sum(r.hops for r in results),
+        injected=sum(r.injected for r in results),
+        stall_per_port=sum(np.asarray(r.stall_per_port) for r in results),
+        completed=all(r.completed for r in results))
 
 
 def sweep(cfg: MachineConfig, request: SweepRequest) -> SweepReport:
@@ -270,28 +346,41 @@ def sweep(cfg: MachineConfig, request: SweepRequest) -> SweepReport:
     wls = (request.workloads if isinstance(request.workloads,
                                            BatchedWorkloads)
            else list(request.workloads))
+    fields = dict(modes=request.modes, geoms=request.geoms,
+                  cycle_hints=request.cycle_hints,
+                  deadlines=request.deadlines)
+    tiled = [w for w in wls if isinstance(w, TiledWorkload)] \
+        if not isinstance(wls, BatchedWorkloads) else []
+    if tiled:
+        wls, fields, counts = _expand_tiles(request)
     if request.validate != "off" and not isinstance(wls, BatchedWorkloads):
         # Static pre-dispatch verification (repro.analysis): reject
         # malformed lanes here, with per-lane diagnostics, instead of
         # letting them poison a shared fabric at runtime.
         from repro.analysis import validate_request
         with span("sweep.validate"):
-            validate_request(wls, modes=request.modes,
+            validate_request(wls, modes=fields["modes"],
                              strict=(request.validate == "strict"),
                              stream_wait_cap=cfg.stream_wait_cap)
     tm: dict = {}
     results = machine._run_many_impl(
-        cfg, wls,
-        modes=None if request.modes is None else list(request.modes),
-        geoms=None if request.geoms is None else list(request.geoms),
-        chunk=request.chunk, pack=request.pack,
+        cfg, wls, chunk=request.chunk, pack=request.pack,
         super_geom=request.super_geom, pack_stats=ps,
-        shard=request.shard,
-        cycle_hints=(None if request.cycle_hints is None
-                     else list(request.cycle_hints)),
-        shard_stats=ss, telemetry=tm,
-        deadlines=(None if request.deadlines is None
-                   else list(request.deadlines)))
+        shard=request.shard, shard_stats=ss, telemetry=tm,
+        **{k: None if v is None else list(v) for k, v in fields.items()})
+    tiles = None
+    if tiled:
+        with span("sweep.tile_fold"):
+            it = iter(results)
+            results = [fold_tiles([next(it) for _ in range(c)])
+                       if isinstance(w, TiledWorkload) else next(it)
+                       for w, c in zip(request.workloads, counts)]
+            budget = sum(len(w.tiles) * w.tiles[0].amq_len.shape[0]
+                         * w.mem_budget for w in tiled)
+            tiles = TileStats(
+                n_tiled_lanes=len(tiled),
+                n_tiles=sum(len(w.tiles) for w in tiled),
+                am_fill=sum(w.n_static_ams for w in tiled) / budget)
     pack = None if ps is None else PackStats(
         n_waves=ps["n_waves"], n_super_lanes=ps["n_super_lanes"],
         packing_efficiency=ps["packing_efficiency"],
@@ -304,4 +393,4 @@ def sweep(cfg: MachineConfig, request: SweepRequest) -> SweepReport:
         engine_calls=tm.get("engine_calls", 0),
         **{k: tm.get(k, 0) for k in machine.TICK_COUNTERS})
     return SweepReport(lanes=tuple(results), pack=pack, shard=shard,
-                       telemetry=telemetry)
+                       telemetry=telemetry, tiles=tiles)

@@ -71,6 +71,7 @@ import numpy as np
 
 from repro.core.am import C_NEXT_PC
 from repro.core.batch import RectPool, SubLane, _rebase_into_super, bucket
+from repro.core.compiler import TiledWorkload
 from repro.core.machine import (TICK_COUNTERS, MachineConfig, MachineState,
                                 RunResult, _get_engine, _host_stats,
                                 _pe_slice_result, engine_call_ticks,
@@ -352,6 +353,11 @@ class SweepService:
         :class:`~repro.analysis.WorkloadValidationError` — co-tenants
         and the service itself are unaffected.
         """
+        if isinstance(workload, TiledWorkload):
+            raise ValueError(
+                f"submit() takes one lane; this workload is tiled into "
+                f"{len(workload.tiles)} lanes (send it through "
+                f"repro.core.sweep.sweep)")
         m = mode_code(self._base_cfg) if mode is None else resolve_mode(mode)
         geom = getattr(workload, "geom", None)
         if geom is None:

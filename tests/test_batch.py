@@ -143,10 +143,13 @@ def test_fabric_size_mismatch_rejected_on_static_path(mixed):
 @pytest.mark.slow
 def test_pending_fifo_overflow_guard(monkeypatch):
     """The consumption-guarantee invariant (machine.run_many's RuntimeError)
-    still fires: with a tiny pending FIFO and the stream throttle disabled,
-    a streaming workload must trip the high-water check."""
+    still fires: with a tiny pending FIFO, the stream throttle disabled
+    and network messages let in on one free slot (no NET_RESERVE, so
+    nothing parks), a streaming workload must trip the high-water
+    check."""
     monkeypatch.setattr(machine, "PEND_CAP", 4)
     monkeypatch.setattr(machine, "STREAM_THROTTLE", 10**9)
+    monkeypatch.setattr(machine, "NET_RESERVE", 1)
     cfg = _cfg()
     a = compiler.random_sparse(16, 16, 0.5, np.random.default_rng(1))
     x = np.random.default_rng(2).integers(-4, 5, size=(16,))
